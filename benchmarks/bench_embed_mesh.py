@@ -15,7 +15,11 @@ bench measures it directly:
   ``_optimize_embedding_jit``) run too, so the shard_map overhead at
   D=1 is visible next to the true baseline.
 
-Each device count runs in its OWN subprocess with
+On an accelerator every device count runs in THIS process, each mesh
+built from ``jax.devices()[:D]``: a chip belongs to one process, so a
+child started after the parent touched JAX could not have it.  Device
+counts above what the host has are reported as skipped.  On the CPU each
+device count runs in its OWN subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` (the flag must be
 set before jax initializes, and the parent process keeps its 1-device
 view).  Virtual host devices share the machine's cores, so CPU numbers
@@ -42,9 +46,10 @@ MARKER = "@@EMBED_MESH@@ "
 DIMS = 8
 
 
-def _worker(devices: int, n: int, knn: int, grid: int, tsne_iters: int,
-            umap_epochs: int) -> None:
-    """Runs inside the subprocess that actually sees ``devices`` devices."""
+def _measure(devices: int, n: int, knn: int, grid: int, tsne_iters: int,
+             umap_epochs: int) -> dict:
+    """Time both sharded embedders on ``jax.devices()[:devices]`` in this
+    process; returns the record."""
     import functools
 
     import jax
@@ -76,7 +81,7 @@ def _worker(devices: int, n: int, knn: int, grid: int, tsne_iters: int,
     it0 = jnp.asarray(0, jnp.int32)
     stage = functools.partial(tsne._sparse_stage_mesh, cfg=tc,
                               count=tsne_iters, grid_size=grid,
-                              interpret=True, mesh=mesh, n=n)
+                              mesh=mesh, n=n)
     rec["tsne_iters_per_sec"] = tsne_iters / time_fn(stage, state, kls,
                                                      ssp, it0)
 
@@ -98,8 +103,7 @@ def _worker(devices: int, n: int, knn: int, grid: int, tsne_iters: int,
     if devices == 1:
         # the true single-device baselines, same sizes
         sstage = functools.partial(tsne._sparse_stage, cfg=tc,
-                                   count=tsne_iters, grid_size=grid,
-                                   interpret=True)
+                                   count=tsne_iters, grid_size=grid)
         s0 = tsne.TsneState(y0[:n], jnp.zeros((n, 2)), jnp.ones((n, 2)))
         rec["tsne_single_iters_per_sec"] = tsne_iters / time_fn(
             sstage, s0, kls, sp, it0)
@@ -107,7 +111,30 @@ def _worker(devices: int, n: int, knn: int, grid: int, tsne_iters: int,
             functools.partial(umap._optimize_embedding_jit, n=n, cfg=uc),
             jax.random.key(1), edges, memb)
 
-    print(MARKER + json.dumps(rec), flush=True)
+    return rec
+
+
+def _in_subprocess(d: int, n: int, knn: int, grid: int, tsne_iters: int,
+                   umap_epochs: int) -> dict:
+    """CPU only: measure D virtual host devices in a child process whose
+    XLA_FLAGS are set before it imports jax."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={d} "
+        + env.get("XLA_FLAGS", "")).strip()
+    cmd = [sys.executable, "-m", "benchmarks.bench_embed_mesh",
+           "--worker", str(d), "--n", str(n), "--knn", str(knn),
+           "--grid", str(grid), "--tsne-iters", str(tsne_iters),
+           "--umap-epochs", str(umap_epochs)]
+    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                         timeout=3600)
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"embed_mesh worker (D={d}) failed:\n{out.stdout}\n"
+            f"{out.stderr}")
+    line = [ln for ln in out.stdout.splitlines()
+            if ln.startswith(MARKER)][-1]
+    return json.loads(line[len(MARKER):])
 
 
 DEFAULT_JSON = None  # resolved lazily: benchmarks.common imports jax
@@ -120,25 +147,18 @@ def run(devices: Sequence[int] = (1, 2, 4, 8), n: int = 20_000,
     from benchmarks.common import Csv, repo_root_json
     if json_out == "__default__":
         json_out = repo_root_json("BENCH_embed_mesh.json")
+    import jax
+
+    from repro.kernels.registry import ACCELERATOR_BACKENDS
+    in_process = jax.default_backend() in ACCELERATOR_BACKENDS
     records = []
     for d in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={d} "
-            + env.get("XLA_FLAGS", "")).strip()
-        cmd = [sys.executable, "-m", "benchmarks.bench_embed_mesh",
-               "--worker", str(d), "--n", str(n), "--knn", str(knn),
-               "--grid", str(grid), "--tsne-iters", str(tsne_iters),
-               "--umap-epochs", str(umap_epochs)]
-        out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                             timeout=3600)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"embed_mesh worker (D={d}) failed:\n{out.stdout}\n"
-                f"{out.stderr}")
-        line = [ln for ln in out.stdout.splitlines()
-                if ln.startswith(MARKER)][-1]
-        rec = json.loads(line[len(MARKER):])
+        if in_process and d > jax.device_count():
+            print(f"# embed_mesh D={d} skipped: {jax.device_count()} "
+                  f"device(s) on this host", flush=True)
+            continue
+        measure = _measure if in_process else _in_subprocess
+        rec = measure(d, n, knn, grid, tsne_iters, umap_epochs)
         records.append(rec)
         print(f"# embed_mesh D={d} "
               f"tsne={rec['tsne_iters_per_sec']:7.2f} it/s "
@@ -163,6 +183,8 @@ def run(devices: Sequence[int] = (1, 2, 4, 8), n: int = 20_000,
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--worker", type=int, default=0,
                     help="internal: run the measurement in THIS process "
@@ -176,8 +198,9 @@ def main() -> None:
     ap.add_argument("--json-out", default="__default__")
     args = ap.parse_args()
     if args.worker:
-        _worker(args.worker, args.n, args.knn, args.grid, args.tsne_iters,
-                args.umap_epochs)
+        rec = _measure(args.worker, args.n, args.knn, args.grid,
+                       args.tsne_iters, args.umap_epochs)
+        print(MARKER + json.dumps(rec), flush=True)
         return
     devices = tuple(int(s) for s in args.devices.split(","))
     print(run(devices=devices, n=args.n, knn=args.knn, grid=args.grid,

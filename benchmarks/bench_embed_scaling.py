@@ -96,6 +96,8 @@ def run(sizes: Sequence[int] = (8192, 16384, 32768, 65536),
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sizes", default="8192,16384,32768,65536")
     ap.add_argument("--dense-max", type=int, default=16384,

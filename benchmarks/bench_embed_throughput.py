@@ -218,6 +218,8 @@ def run(sizes: Sequence[int] = (16384, 65536, 262144), block: int = 512,
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sizes", default="16384,65536,262144")
     ap.add_argument("--block", type=int, default=512)

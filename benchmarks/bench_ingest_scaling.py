@@ -139,6 +139,8 @@ def run(sizes: Sequence[int] = (8192, 65536, 262144, 1048576),
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sizes", default="8192,65536,262144,1048576")
     ap.add_argument("--chunk", type=int, default=8192)

@@ -14,10 +14,11 @@ auto-vs-XLA comparison into a hard CI gate) and (b) the tracked
 per-mode trajectory in ``BENCH_kernels.json`` (backend-stamped by
 ``common.emit_json``, so baselines are only compared within a backend).
 
-``--autotune`` sweeps tile-size candidates for each tunable op and
-persists winners to the registry's autotune cache (keyed by
-``backend/op/shape-bucket``) — a one-off pass on real hardware that
-keeps paying off across processes.
+``--autotune OUT.json`` sweeps tile-size candidates for each tunable op
+and writes the winners to OUT.json (keyed by ``backend/op/shape-bucket``).
+The program never reads that file by itself: its tile sizes come from
+the committed ``registry._TILE_TABLE``, so a winner takes effect once
+someone copies it into that table.
 """
 from __future__ import annotations
 
@@ -100,7 +101,8 @@ def _maxdiff(a: np.ndarray, b: np.ndarray) -> float:
 
 
 def run(n: int = 1 << 16, *, smoke: bool = False,
-        json_out: Optional[str] = None, autotune: bool = False) -> str:
+        json_out: Optional[str] = None,
+        autotune: Optional[str] = None) -> str:
     """Bench (or, with ``smoke=True``, gate) every registered op.
 
     ``smoke`` shrinks the problem, keeps median-of-3 timing, and turns
@@ -159,7 +161,7 @@ def run(n: int = 1 << 16, *, smoke: bool = False,
                     f"(rtol={_RTOL}, atol={_ATOL})")
 
     if autotune:
-        for row in _run_autotune(n, v):
+        for row in _run_autotune(n, v, autotune):
             csv.add(*row)
 
     payload = {"bench": "kernels", "n": n, "smoke": smoke, "ops": ops_json}
@@ -172,10 +174,10 @@ def run(n: int = 1 << 16, *, smoke: bool = False,
     return csv.dump(title)
 
 
-def _run_autotune(n: int, v: Dict[str, jnp.ndarray]):
+def _run_autotune(n: int, v: Dict[str, jnp.ndarray], out_path: str):
     """Sweep tile candidates through the PUBLIC wrappers (so padding
-    logic sees each candidate) and persist winners to the registry
-    autotune cache.  Yields CSV rows describing each winner."""
+    logic sees each candidate) and write winners to ``out_path``.
+    Yields CSV rows describing each winner."""
     backend = jax.default_backend()
     # the best pallas tier this backend actually runs; nothing to tune
     # when auto already lands on the pure-XLA path everywhere
@@ -205,7 +207,7 @@ def _run_autotune(n: int, v: Dict[str, jnp.ndarray]):
     for op, (candidates, measure) in sweeps.items():
         try:
             best = registry.autotune_op(
-                op, candidates, measure,
+                op, candidates, measure, cache_path=out_path,
                 bucket=registry.shape_bucket((n,)))
         except registry.KernelUnavailableError as e:
             yield (op, mode, backend, "skipped", "", f"autotune: {e}")
@@ -214,14 +216,16 @@ def _run_autotune(n: int, v: Dict[str, jnp.ndarray]):
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n", type=int, default=1 << 16)
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: assert auto resolution matches the "
                          "XLA reference per op (small n)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="sweep tile-size candidates and persist winners "
-                         "to the registry autotune cache")
+    ap.add_argument("--autotune", default=None, metavar="OUT_JSON",
+                    help="sweep tile-size candidates and write the "
+                         "winners to OUT_JSON")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write BENCH_kernels-style JSON here "
                          "(default: no file)")

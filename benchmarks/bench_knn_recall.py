@@ -128,6 +128,8 @@ def run_smoke(n: int = 4096, k: int = 15, dims: int = 8,
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sizes", default="16384,65536,262144")
     ap.add_argument("--k", type=int, default=90)

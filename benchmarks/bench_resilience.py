@@ -195,6 +195,8 @@ def run_smoke(json_out: Optional[str] = "BENCH_resilience_ci.json") -> str:
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200_000)
     ap.add_argument("--n-shards", type=int, default=8)

@@ -169,6 +169,8 @@ def run_smoke(json_out: Optional[str] = "BENCH_service_ci.json") -> str:
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=400_000)
     ap.add_argument("--drift-frac", type=float, default=0.05)

@@ -18,7 +18,8 @@ Emits CSV blocks (name, value, paper reference) for:
   * ingest_scaling       — streaming vs one-shot sketch-stage memory vs N
   * ingest_throughput    — points/sec: two-sort vs fused vs fused+superbatch
   * embed_mesh           — sharded embed stage iters/sec vs device count
-                           (one subprocess per D, virtual CPU devices)
+                           (in this process on an accelerator; one
+                           subprocess per D of virtual CPU devices)
   * knn_recall           — approximate (sketch bucketing + NN-descent) vs
                            exact kNN build: recall + wall-clock
   * service              — online service: ingest absorption points/sec,
@@ -118,6 +119,8 @@ def build_jobs(fast: bool):
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="reduced sizes (CI-friendly)")

@@ -47,6 +47,8 @@ from repro.data.synthetic import MixtureSpec                  # noqa: E402
 
 
 def main():
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200_000)
     ap.add_argument("--clusters", type=int, default=8)

@@ -30,6 +30,8 @@ from repro.train.steps import (make_prefill_step,              # noqa: E402
 
 
 def main():
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=ARCH_IDS)
     ap.add_argument("--batch", type=int, default=4)

@@ -37,6 +37,8 @@ from repro.data.synthetic import MixtureSpec                  # noqa: E402
 
 
 def main():
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=100_000)
     ap.add_argument("--drift-frac", type=float, default=0.08)

@@ -25,6 +25,8 @@ from repro.data.synthetic import (MixtureSpec,                # noqa: E402
 
 
 def main():
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=400_000)
     ap.add_argument("--shards", type=int, default=32)

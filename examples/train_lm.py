@@ -26,6 +26,8 @@ from repro.train.trainer import Trainer, TrainerConfig         # noqa: E402
 
 
 def main():
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--d-model", type=int, default=128)

@@ -96,7 +96,9 @@ class AnnConfig:
                     carry nearly all the signal (0 = all k slots)
     block           row block for the refinement distance pass
     tile            stage-1 distance backend: "xla" | "pallas"
-    interpret       run the Pallas kernel in interpret mode (CPU)
+    interpret       Pallas tier for tile="pallas": None resolves through
+                    the kernel registry (compiled on an accelerator,
+                    interpret on CPU); True/False force it
     auto_threshold  knn_graph(method="auto") switches to ann above this N
     seed            RNG seed for rotations and descent sampling
     """
@@ -110,7 +112,7 @@ class AnnConfig:
     rev_cols: int = 32
     block: int = 4096
     tile: str = "xla"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     # registry dispatch mode for the stage-1 distance kernel (op
     # "knn_dist_tiles"); None defers to tile/interpret above (plus any
     # process-level SNS_KERNEL_MODE pin), a string forces one mode

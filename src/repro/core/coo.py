@@ -63,25 +63,23 @@ def segment_reduce(vals: jnp.ndarray, bounds: jnp.ndarray,
     at E ~ 10⁷).  Returns (N,) or (N, D).
 
     ``mode`` routes through the kernel registry (op ``segment_reduce``):
-    None or "xla" keeps the cumsum-difference path below (the CPU
-    default, unless the process pins another mode via SNS_KERNEL_MODE /
-    a registry override); "interpret"/"compiled" run the fused Pallas
-    kernel (``kernels.segment_reduce``), "auto" resolves per backend —
-    on accelerators that picks the fused kernel, on CPU it falls back
-    to the cumsum path.
+    None, "auto" and "xla" keep the cumsum-difference path below (auto
+    never picks the fused kernel, unless the process pins another mode
+    via SNS_KERNEL_MODE / a registry override); "interpret"/"compiled"
+    run the fused Pallas kernel (``kernels.segment_reduce``), whose
+    compiled tier refuses shapes that exceed its VMEM budget.
     """
-    if mode is None or mode == "auto":
-        from repro.kernels import registry
-        pinned = registry.resolve_mode(None, "segment_reduce")
-        mode = pinned if pinned != "auto" else mode
-    if mode not in (None, "xla"):
-        from repro.kernels import registry
-        impl = registry.resolve("segment_reduce", mode=mode,
-                                shape=vals.shape, dtype=vals.dtype)
-        if impl.mode != "xla":
-            return impl.fn(vals, bounds,
-                           **registry.tile_params("segment_reduce",
-                                                  shape=vals.shape))
+    from repro.kernels import registry
+    m = registry.resolve_mode(None if mode in (None, "auto") else mode,
+                              "segment_reduce")
+    if m not in ("auto", "xla"):
+        width = 1 if vals.ndim == 1 else vals.shape[1]
+        impl = registry.resolve(
+            "segment_reduce", mode=m, dtype=vals.dtype,
+            shape=(bounds.shape[0] - 1, vals.shape[0], width))
+        return impl.fn(vals, bounds,
+                       **registry.tile_params("segment_reduce",
+                                              shape=vals.shape))
     zero = jnp.zeros((1,) + vals.shape[1:], vals.dtype)
     cs = jnp.concatenate([zero, jnp.cumsum(vals, axis=0)])
     return cs[bounds[1:]] - cs[bounds[:-1]]

@@ -6,8 +6,8 @@ joined it in this PR (``core.tsne``/``core.umap``: row-block-sharded
 iteration loops).  This module hoists the pieces both sides need so no
 stage carries its own copy:
 
-* :func:`shard_map_compat` — ``jax.shard_map`` across the API move
-  (``check_vma`` vs the older ``jax.experimental.shard_map.check_rep``);
+* :func:`shard_map_compat` — ``jax.shard_map`` with replication checks
+  off, the one spelling every stage uses;
 * :func:`make_embed_mesh` / :func:`resolve_mesh` — build or normalize the
   1-D embed mesh ``SnsConfig.embed_mesh`` names (``None`` | device count |
   a ready ``Mesh``);
@@ -39,21 +39,16 @@ EMBED_AXIS = "embed"
 
 
 def shard_map_compat(*, mesh, in_specs, out_specs):
-    """Decorator: ``jax.shard_map`` with replication checks off, across the
-    API move (new ``jax.shard_map(check_vma=)`` vs the older
-    ``jax.experimental.shard_map.shard_map(check_rep=)``)."""
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return functools.partial(_sm, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+    """Decorator: ``jax.shard_map`` with replication checks off."""
+    return functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
 
 def make_embed_mesh(n_devices: Optional[int] = None,
                     axis: str = EMBED_AXIS) -> Mesh:
     """A 1-D mesh over the first ``n_devices`` local devices (all by
-    default) — the topology the row-block-sharded embed stage runs on."""
+    default), one row block per device — the topology the
+    row-block-sharded embed stage runs on."""
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if not 1 <= n <= len(devs):

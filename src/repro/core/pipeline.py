@@ -86,10 +86,11 @@ class SnsConfig:
     # collective contract in core.mesh
     embed_mesh: object = None      # None | int | jax.sharding.Mesh
     # Pallas kernel dispatch tier for the embed stage (kernels.registry):
-    # "auto" = compiled → interpret → xla for the current backend;
-    # "compiled"|"interpret"|"xla" force one tier for every registry op
-    # (cic splat/gather, tSNE force tile, kNN distance scan, the fused
-    # segment-reduce).  CPU CI pins interpret/xla; accelerators keep auto.
+    # "auto" = the registry's pick for the current backend (compiled, else
+    # xla, on an accelerator; interpret, else xla, on CPU; the fused
+    # segment-reduce only when forced); "compiled"|"interpret"|"xla"
+    # force one tier for every registry op (cic splat/gather, tSNE force
+    # tile, kNN distance scan, the fused segment-reduce).
     kernel_mode: str = "auto"
     seed: int = 0
 

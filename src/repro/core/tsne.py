@@ -50,8 +50,9 @@ Two further sparse-backend knobs (this PR's follow-ups to the above):
   doubles (grid_size → grid_max) whenever the embedding span outgrows
   the spacing, FIt-SNE-style, retracing only at doubling boundaries;
 * ``cic="pallas"`` — the cloud-in-cell splat/gather runs as the one-hot
-  matmul Pallas tile in ``repro.kernels.cic`` (MXU on TPU,
-  interpret-mode on CPU) instead of the XLA scatter/gather loop.
+  matmul Pallas tile in ``repro.kernels.cic`` (compiled for the MXU on
+  an accelerator, the Pallas interpreter on CPU) instead of the XLA
+  scatter/gather loop.
 
 The per-edge attraction reduction goes through the shared sorted-COO
 core (:mod:`repro.core.coo`) — the same scatter-free machinery the UMAP
@@ -121,8 +122,9 @@ class TsneConfig:
     ann: Optional[object] = None
     # kernel dispatch mode for every Pallas call site (CIC splat/gather,
     # fused force tile, segment reduce), via kernels.registry: "auto"
-    # resolves compiled → interpret → xla per backend; the other values
-    # force one mode end-to-end (SnsConfig.kernel_mode threads to here)
+    # takes the registry's per-backend pick (never the interpreter on an
+    # accelerator); the other values force one mode end-to-end
+    # (SnsConfig.kernel_mode threads to here)
     kernel_mode: str = "auto"
 
 
@@ -527,6 +529,30 @@ def fft_repulsion(y: jnp.ndarray, grid_size: int = 128, *,
     return rep, z
 
 
+def _edge_attraction(y: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
+                     pe: jnp.ndarray, bounds: jnp.ndarray,
+                     mode: Optional[str]
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Attraction Σ_j pe_ij·num_ij·(y_i − y_j) per src row, and num per
+    edge, for a src-sorted edge list with row ``bounds``.
+
+    The reduction is scatter-free: Σ over row i is a cumsum difference
+    at the precomputed row bounds — one vectorized O(E) pass (XLA CPU
+    scatter walks updates serially, ~100× slower at E ~ 10⁷), shared
+    with the UMAP epoch loop.  Per-edge values stay 1-D, one array per
+    embedding coordinate: a TPU tiles the last dim of an array to 128
+    lanes, so an (E, 2) intermediate takes 64x its size on the chip
+    (at CANCER_1M's E = 1.8e8 one such array alone would be 92 GB).  The
+    arithmetic is element for element the (E, 2) form's.
+    """
+    diff = [y[:, c][src] - y[:, c][dst] for c in range(y.shape[1])]
+    num = 1.0 / (1.0 + sum(d * d for d in diff))             # (E,)
+    w = pe * num
+    att = jnp.stack([coo.segment_reduce(w * d, bounds, mode=mode)
+                     for d in diff], axis=1)
+    return att, num
+
+
 def sparse_grad(y: jnp.ndarray, sp: SparseP, exaggeration=1.0,
                 grid_size: int = 128, *, cic: str = "xla",
                 interpret: Optional[bool] = None,
@@ -540,16 +566,8 @@ def sparse_grad(y: jnp.ndarray, sp: SparseP, exaggeration=1.0,
     (``cic``/``interpret`` select its splat/gather path).
     """
     exaggeration = jnp.asarray(exaggeration, jnp.float32)
-    ys, yd = y[sp.src], y[sp.dst]
-    diff = ys - yd
-    num = 1.0 / (1.0 + jnp.sum(diff * diff, axis=1))         # (E,)
     pe = exaggeration * sp.val
-    # row-wise reduction WITHOUT scatter: edges are pre-sorted by src, so
-    # Σ over row i = cumsum difference at the precomputed row bounds —
-    # one vectorized O(E) pass (XLA CPU scatter walks updates serially,
-    # ~100× slower at E ~ 10⁷); shared with the UMAP epoch loop
-    att = coo.segment_reduce((pe * num)[:, None] * diff, sp.bounds,
-                             mode=mode)
+    att, num = _edge_attraction(y, sp.src, sp.dst, pe, sp.bounds, mode)
     rep, z = fft_repulsion(y, grid_size, cic=cic, interpret=interpret,
                            mode=mode)
     grad = 4.0 * (att - rep / z)
@@ -662,14 +680,11 @@ def sparse_grad_shard(y_blk: jnp.ndarray, layout: coo.ShardedEdgeLayout,
     exaggeration = jnp.asarray(exaggeration, jnp.float32)
     rows_per = layout.src_bounds.shape[0] - 1
     n_pad = layout.dst_bounds.shape[0] - 1
-    ys, yd = y_full[layout.src], y_full[layout.dst]
-    diff = ys - yd
-    num = 1.0 / (1.0 + jnp.sum(diff * diff, axis=1))         # (Ep,)
     pe = exaggeration * val                                  # 0 on padding
     # local rows own their full edge slice (blocks split at row
     # boundaries), so the attraction reduction is entirely local
-    att = coo.segment_reduce((pe * num)[:, None] * diff, layout.src_bounds,
-                             mode=mode)
+    att, num = _edge_attraction(y_full, layout.src, layout.dst, pe,
+                                layout.src_bounds, mode)
     live_blk = layout.row_offset + jnp.arange(rows_per) < n
     live_full = jnp.arange(n_pad) < n
     rep, z = _fft_repulsion_shard(y_blk, live_blk, y_full, live_full,
@@ -702,12 +717,11 @@ def _momentum_update_shard(state: TsneState, grad: jnp.ndarray, mom,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "count", "grid_size",
-                                             "interpret", "mesh", "n"))
+                                             "mesh", "n"))
 def _sparse_stage_mesh(state: TsneState, kls: jnp.ndarray,
                        ssp: ShardedSparseP, it0: jnp.ndarray, *,
                        cfg: TsneConfig, count: int, grid_size: int,
-                       interpret: bool, mesh, n: int
-                       ) -> Tuple[TsneState, jnp.ndarray]:
+                       mesh, n: int) -> Tuple[TsneState, jnp.ndarray]:
     """``count`` mesh-parallel optimizer iterations at a fixed grid size —
     the sharded twin of :func:`_sparse_stage`.  State rows and edge slices
     stay on their devices across the whole ``fori_loop``; per iteration the
@@ -735,8 +749,7 @@ def _sparse_stage_mesh(state: TsneState, kls: jnp.ndarray,
             y_full = jax.lax.all_gather(st.y, axis, axis=0, tiled=True)
             grad, kl = sparse_grad_shard(
                 st.y, lay, val, y_full, exag, grid_size, axis, n,
-                cic=cfg.cic, interpret=interpret,
-                mode=_cfg_kernel_mode(cfg))
+                cic=cfg.cic, mode=_cfg_kernel_mode(cfg))
             st = _momentum_update_shard(st, grad, mom, cfg, axis,
                                         live_blk, n)
             return st, kls.at[it].set(kl)
@@ -747,7 +760,7 @@ def _sparse_stage_mesh(state: TsneState, kls: jnp.ndarray,
 
 
 def _run_tsne_sparse_mesh(key: jax.Array, x: jnp.ndarray, weights, init, *,
-                          cfg: TsneConfig, mesh, interpret: bool
+                          cfg: TsneConfig, mesh
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mesh-parallel sparse optimizer (fixed or span-adaptive G).
 
@@ -778,7 +791,7 @@ def _run_tsne_sparse_mesh(key: jax.Array, x: jnp.ndarray, weights, init, *,
             min(cfg.adaptive_interval, cfg.n_iter - it)
         state, kls = _sparse_stage_mesh(
             state, kls, ssp, jnp.asarray(it, jnp.int32), cfg=cfg,
-            count=count, grid_size=g, interpret=interpret, mesh=mesh, n=n)
+            count=count, grid_size=g, mesh=mesh, n=n)
         it += count
         if cfg.grid_interval > 0 and it < cfg.n_iter:
             y_live = state.y[:n]
@@ -941,9 +954,9 @@ def _phase(i, cfg: TsneConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return exag, mom
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "backend", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "backend"))
 def _run_tsne(key: jax.Array, x: jnp.ndarray, weights, init, *,
-              cfg: TsneConfig, backend: str, interpret: bool
+              cfg: TsneConfig, backend: str
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     n = x.shape[0]
     if backend == "sparse":
@@ -955,8 +968,7 @@ def _run_tsne(key: jax.Array, x: jnp.ndarray, weights, init, *,
 
         def grad_fn(y, exag):
             return sparse_grad(y, sp, exag, grid_size=cfg.grid_size,
-                               cic=cfg.cic, interpret=interpret,
-                               mode=_cfg_kernel_mode(cfg))
+                               cic=cfg.cic, mode=_cfg_kernel_mode(cfg))
     else:
         stats = calibrate_stats(x, cfg.perplexity, weights=weights,
                                 search_iters=cfg.sigma_search_iters,
@@ -969,7 +981,7 @@ def _run_tsne(key: jax.Array, x: jnp.ndarray, weights, init, *,
         else:
             def grad_fn(y, exag):
                 return embedding_grad(x, y, stats, exag, backend=backend,
-                                      block=cfg.block, interpret=interpret,
+                                      block=cfg.block,
                                       mode=_cfg_kernel_mode(cfg))
 
     y0 = init if init is not None else \
@@ -1015,12 +1027,10 @@ def _sparse_setup(key: jax.Array, x: jnp.ndarray, weights, init, *,
                          gains=jnp.ones_like(y0))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "count", "grid_size",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("cfg", "count", "grid_size"))
 def _sparse_stage(state: TsneState, kls: jnp.ndarray, sp: SparseP,
                   it0: jnp.ndarray, *, cfg: TsneConfig, count: int,
-                  grid_size: int, interpret: bool
-                  ) -> Tuple[TsneState, jnp.ndarray]:
+                  grid_size: int) -> Tuple[TsneState, jnp.ndarray]:
     """``count`` optimizer iterations at a fixed grid size.
 
     ``it0`` (the global iteration offset) is traced, so the stage function
@@ -1032,8 +1042,7 @@ def _sparse_stage(state: TsneState, kls: jnp.ndarray, sp: SparseP,
         it = it0 + i
         exag, mom = _phase(it, cfg)
         grad, kl = sparse_grad(state.y, sp, exag, grid_size=grid_size,
-                               cic=cfg.cic, interpret=interpret,
-                               mode=_cfg_kernel_mode(cfg))
+                               cic=cfg.cic, mode=_cfg_kernel_mode(cfg))
         return _momentum_update(state, grad, mom, cfg), kls.at[it].set(kl)
 
     return jax.lax.fori_loop(0, count, step, (state, kls))
@@ -1048,7 +1057,7 @@ def _grid_for_span(span: float, g: int, cfg: TsneConfig) -> int:
 
 
 def _run_tsne_sparse_adaptive(key: jax.Array, x: jnp.ndarray, weights, init,
-                              *, cfg: TsneConfig, interpret: bool
+                              *, cfg: TsneConfig
                               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Staged sparse optimizer with span-adaptive repulsion grid."""
     sp, state = _sparse_setup(key, x, weights, init, cfg=cfg)
@@ -1059,7 +1068,7 @@ def _run_tsne_sparse_adaptive(key: jax.Array, x: jnp.ndarray, weights, init,
         count = min(cfg.adaptive_interval, cfg.n_iter - it)
         state, kls = _sparse_stage(
             state, kls, sp, jnp.asarray(it, jnp.int32), cfg=cfg,
-            count=count, grid_size=g, interpret=interpret)
+            count=count, grid_size=g)
         it += count
         span = float(jnp.max(jnp.max(state.y, axis=0)
                              - jnp.min(state.y, axis=0)))
@@ -1074,11 +1083,13 @@ def run_tsne(key: jax.Array, x: jnp.ndarray, cfg: TsneConfig,
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full tSNE: returns (embedding (N, dims), KL trace (n_iter,)).
 
-    ``backend`` overrides ``cfg.backend``; Pallas interpret mode is
-    auto-selected off-TPU.  ``mesh`` (``None`` | device count | 1-D
-    ``Mesh``, see ``core.mesh``) runs the whole sparse optimizer
-    row-block-sharded under ``shard_map`` — sparse backend only (the
-    dense/tiled/pallas backends are O(N²) and stay single-device).
+    ``backend`` overrides ``cfg.backend``; Pallas call sites resolve
+    their tier through ``kernels.registry`` (``cfg.kernel_mode``; auto is
+    compiled on an accelerator, interpret on the CPU).  ``mesh`` (``None``
+    | device count | 1-D ``Mesh``, see ``core.mesh``) runs the whole
+    sparse optimizer row-block-sharded under ``shard_map`` — sparse
+    backend only (the dense/tiled/pallas backends are O(N²) and stay
+    single-device).
 
     ``init`` seeds the optimizer at the given (N, dims) float coordinates
     instead of the 1e-4·normal cold start — the warm-start hook the
@@ -1108,16 +1119,13 @@ def run_tsne(key: jax.Array, x: jnp.ndarray, cfg: TsneConfig,
         y0 = init if init is not None else \
             1e-4 * jax.random.normal(key, (x.shape[0], cfg.dims))
         return y0, jnp.zeros((0,), jnp.float32)
-    interpret = jax.default_backend() != "tpu"
     mesh = mesh_mod.resolve_mesh(mesh)
     if mesh is not None:
         if backend != "sparse":
             raise ValueError(
                 f"mesh-parallel tSNE needs backend='sparse'; got {backend!r}")
         return _run_tsne_sparse_mesh(key, x, weights, init, cfg=cfg,
-                                     mesh=mesh, interpret=interpret)
+                                     mesh=mesh)
     if backend == "sparse" and cfg.grid_interval > 0:
-        return _run_tsne_sparse_adaptive(key, x, weights, init, cfg=cfg,
-                                         interpret=interpret)
-    return _run_tsne(key, x, weights, init, cfg=cfg, backend=backend,
-                     interpret=interpret)
+        return _run_tsne_sparse_adaptive(key, x, weights, init, cfg=cfg)
+    return _run_tsne(key, x, weights, init, cfg=cfg, backend=backend)

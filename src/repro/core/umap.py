@@ -83,8 +83,8 @@ class UmapConfig:
     knn_method: str = "auto"
     ann: Optional[object] = None
     # kernel dispatch mode for the segment-reduce call sites (see
-    # kernels.registry): "auto" keeps the cumsum path on CPU and the
-    # fused kernel on accelerators; other values force one mode
+    # kernels.registry): "auto" keeps the cumsum path on every backend;
+    # other values force one mode
     kernel_mode: str = "auto"
 
 
@@ -186,36 +186,61 @@ def epoch_delta(y: jnp.ndarray, layout: coo.EdgeLayout, memb_n: jnp.ndarray,
     """
     n = y.shape[0]
     e = layout.src.shape[0]
-    src, dst = layout.src, layout.dst
-    ys, yd = y[src], y[dst]
-    d2 = jnp.sum((ys - yd) ** 2, axis=1)
+    # repulsive: neg_rate uniform negatives per edge
+    neg = jax.random.randint(kneg, (e, neg_rate), 0, n)
+    src_tot, att = _edge_forces(y, layout.src, layout.dst, neg, memb_n,
+                                a, b)
+    # scatter-free reduction: src side via the src-sorted bounds, dst
+    # side (the attraction reaction, −att) via the precomputed gather
+    # into dst-sorted order — two O(E) cumsum passes, no .at[].add
+    return _reduce_cols(src_tot, layout.src_bounds, mode) \
+        - _reduce_cols([c[layout.dst_order] for c in att],
+                       layout.dst_bounds, mode)
+
+
+def _edge_forces(y: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
+                 neg: jnp.ndarray, memb_n: jnp.ndarray, a: float, b: float):
+    """Per-edge SGD forces, one 1-D (E,) array per embedding coordinate.
+
+    Returns (src_tot, att): per coordinate, the attraction plus the
+    summed negative-sample repulsion on the edge's src point, and the
+    attraction alone (its dst point takes the reaction).  ``neg`` is the
+    (E, R) negative-sample draw.  Per-edge values stay 1-D (and the
+    negatives (R, E)) because a TPU tiles the last dim of an array to 128
+    lanes: (E, dims) and (E, R, dims) intermediates would take 32-64x
+    their size on the chip.  The arithmetic is element for element the
+    (E, dims) form's.
+    """
+    cols = [y[:, c] for c in range(y.shape[1])]
+    diff = [yc[src] - yc[dst] for yc in cols]
+    d2 = sum(d * d for d in diff)
     # attractive: dCE/dy = 2ab d^{2(b-1)} / (1 + a d^{2b}) * (ys - yd)
     grad_coef = (-2.0 * a * b * d2 ** (b - 1.0)
                  / (1.0 + a * d2 ** b))
     grad_coef = jnp.where(d2 > 0, grad_coef, 0.0)
-    att = jnp.clip(grad_coef[:, None] * (ys - yd), -4.0, 4.0) \
-        * memb_n[:, None]
-    # repulsive: neg_rate uniform negatives per edge.  A draw can hit
-    # the edge's own endpoints — repelling dst would fight the very
-    # attraction this edge just applied (src is harmless: zero diff),
-    # so those samples are masked out rather than resampled (keeps
-    # shapes static; the tiny rate loss matches umap-learn's "skip
-    # self" behaviour in expectation).
-    neg = jax.random.randint(kneg, (e, neg_rate), 0, n)
-    valid = (neg != src[:, None]) & (neg != dst[:, None])
-    yn = y[neg]                                           # (E, R, dims)
-    dn2 = jnp.sum((ys[:, None, :] - yn) ** 2, axis=2)
+    att = [jnp.clip(grad_coef * d, -4.0, 4.0) * memb_n for d in diff]
+    # A negative draw can hit the edge's own endpoints — repelling dst
+    # would fight the very attraction this edge just applied (src is
+    # harmless: zero diff), so those samples are masked out rather than
+    # resampled (keeps shapes static; the tiny rate loss matches
+    # umap-learn's "skip self" behaviour in expectation).
+    neg = neg.T                                            # (R, E)
+    valid = (neg != src[None, :]) & (neg != dst[None, :])
+    ndiff = [yc[src][None, :] - yc[neg] for yc in cols]    # (R, E) each
+    dn2 = sum(d * d for d in ndiff)
     rep_coef = (2.0 * b) / ((0.001 + dn2) * (1.0 + a * dn2 ** b))
-    rep = jnp.clip(rep_coef[..., None] * (ys[:, None, :] - yn),
-                   -4.0, 4.0) * memb_n[:, None, None]
-    rep = jnp.where(valid[..., None], rep, 0.0)
-    # scatter-free reduction: src side via the src-sorted bounds, dst
-    # side (the attraction reaction, −att) via the precomputed gather
-    # into dst-sorted order — two O(E) cumsum passes, no .at[].add
-    return coo.segment_reduce(att + jnp.sum(rep, axis=1),
-                              layout.src_bounds, mode=mode) \
-        - coo.segment_reduce(att[layout.dst_order], layout.dst_bounds,
-                             mode=mode)
+    src_tot = [
+        t + jnp.sum(jnp.where(valid, jnp.clip(rep_coef * d, -4.0, 4.0)
+                              * memb_n[None, :], 0.0), axis=0)
+        for t, d in zip(att, ndiff)]
+    return src_tot, att
+
+
+def _reduce_cols(cols, bounds: jnp.ndarray, mode: Optional[str]
+                 ) -> jnp.ndarray:
+    """Segment-reduce each 1-D per-edge column; (rows, len(cols))."""
+    return jnp.stack([coo.segment_reduce(c, bounds, mode=mode)
+                      for c in cols], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n"))
@@ -263,26 +288,13 @@ def epoch_delta_shard(y_blk: jnp.ndarray, y_full: jnp.ndarray,
     reaction reduces into a full-length per-block partial and crosses
     devices as ONE ``psum`` — no scatter anywhere.
     """
-    src, dst = lay.src, lay.dst                          # global ids (Ep,)
-    ys, yd = y_full[src], y_full[dst]
-    d2 = jnp.sum((ys - yd) ** 2, axis=1)
-    grad_coef = (-2.0 * a * b * d2 ** (b - 1.0)
-                 / (1.0 + a * d2 ** b))
-    grad_coef = jnp.where(d2 > 0, grad_coef, 0.0)
-    att = jnp.clip(grad_coef[:, None] * (ys - yd), -4.0, 4.0) \
-        * memb_n[:, None]                                # 0 on padded slots
     neg = jax.random.randint(kneg, (e_total, neg_rate), 0, n)[lay.edge_ids]
-    valid = (neg != src[:, None]) & (neg != dst[:, None])
-    yn = y_full[neg]                                     # (Ep, R, dims)
-    dn2 = jnp.sum((ys[:, None, :] - yn) ** 2, axis=2)
-    rep_coef = (2.0 * b) / ((0.001 + dn2) * (1.0 + a * dn2 ** b))
-    rep = jnp.clip(rep_coef[..., None] * (ys[:, None, :] - yn),
-                   -4.0, 4.0) * memb_n[:, None, None]
-    rep = jnp.where(valid[..., None], rep, 0.0)
-    src_red = coo.segment_reduce(att + jnp.sum(rep, axis=1),
-                                 lay.src_bounds, mode=mode)  # (rows_per, dims)
-    dst_part = coo.segment_reduce(att[lay.dst_order],
-                                  lay.dst_bounds, mode=mode)  # (n_pad, dims)
+    # padded slots carry memb 0, so all their forces are 0
+    src_tot, att = _edge_forces(y_full, lay.src, lay.dst, neg, memb_n,
+                                a, b)
+    src_red = _reduce_cols(src_tot, lay.src_bounds, mode)  # (rows_per, dims)
+    dst_part = _reduce_cols([c[lay.dst_order] for c in att],
+                            lay.dst_bounds, mode)          # (n_pad, dims)
     dst_tot = jax.lax.psum(dst_part, axis)               # THE dst exchange
     rows_per = lay.src_bounds.shape[0] - 1
     dst_blk = jax.lax.dynamic_slice_in_dim(dst_tot, lay.row_offset,

@@ -39,8 +39,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import registry
+
+# The splat keeps its whole (C, G, G) accumulator resident in VMEM.  At the
+# adaptive-grid cap G = 1024 that is 12 MiB, over the 16 MiB default
+# scoped limit once the one-hot temporaries are added; 64 MiB (half of a
+# v5e core's VMEM) compiles for ``v5e`` (tests/test_tpu_compile.py).
+_SPLAT_VMEM_LIMIT = 64 << 20
 
 
 def _onehot_weights(i0: jnp.ndarray, f: jnp.ndarray, g: int):
@@ -99,6 +106,8 @@ def cic_splat(i0: jnp.ndarray, f: jnp.ndarray, vals: jnp.ndarray,
                                lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c, grid_size, grid_size),
                                        jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SPLAT_VMEM_LIMIT),
         interpret=interpret,
     )(i0, f.astype(jnp.float32), vals.astype(jnp.float32))
 

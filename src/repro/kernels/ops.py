@@ -104,8 +104,8 @@ def cic_splat(i0: jnp.ndarray, f: jnp.ndarray, vals: jnp.ndarray,
     (op ``cic_splat``): ``mode`` forces a registry mode; the legacy
     ``interpret`` flag is a backend-derived default that a process-level
     pin (override / ``SNS_KERNEL_MODE``) beats; both-None resolves
-    compiled → interpret → xla for the current backend.  ``block_items``
-    None consults the per-backend tile table (autotune-cache aware).
+    per backend (``registry.resolve``).  ``block_items`` None consults
+    the per-backend tile table.
     """
     impl = registry.resolve("cic_splat",
                             mode=registry.legacy_mode("cic_splat",

@@ -16,11 +16,15 @@ at each call.  Each op registers up to three implementations:
 Resolution order under ``mode="auto"`` is compiled → interpret → xla:
 the first implementation whose ``prefer`` predicate accepts the current
 ``(backend, shape, dtype)`` wins.  ``prefer`` is the *auto-ordering*
-preference (e.g. the segment-reduce interpret kernel declines CPU so the
-cumsum-difference XLA path stays the CPU default), while ``supported``
-is the hard capability gate (compiled kernels cannot run on CPU at all,
-so forcing ``mode="compiled"`` there fails loudly rather than silently
-falling back — a CI box must never *think* it exercised Mosaic).
+preference (e.g. the segment-reduce kernel never volunteers in auto
+mode, so the cumsum-difference XLA path stays the default everywhere),
+while ``supported`` is the hard capability gate (compiled kernels cannot
+run on CPU at all, and a compiled kernel whose VMEM working set does not
+fit refuses the shape, so forcing ``mode="compiled"`` there fails loudly
+rather than silently falling back — a CI box must never *think* it
+exercised Mosaic).  On an accelerator backend auto mode never picks the
+interpret tier: a compiled tier that declines falls through to the XLA
+reference, and the Pallas interpreter runs on a chip only when forced.
 
 Mode precedence, highest first:
 
@@ -36,10 +40,11 @@ never share a compilation cache entry.  ``SnsConfig.kernel_mode`` feeds
 (1) through the config plumbing in ``core.pipeline``.
 
 The module also owns the per-backend tile-size table (VMEM-conscious
-defaults for compiled grids) and an optional empirical autotune cache:
-winners are persisted to JSON keyed by ``(backend, op, shape-bucket)``
-so a one-off ``bench_kernels --autotune`` pass on real hardware keeps
-paying off across processes.
+defaults for compiled grids).  The program reads tile sizes from that
+committed table only; an empirical autotune pass
+(``bench_kernels --autotune OUT.json``) writes its winners to an
+explicit JSON file keyed by ``(backend, op, shape-bucket)``, which
+:func:`tile_params` consults only when handed that path.
 """
 from __future__ import annotations
 
@@ -53,7 +58,6 @@ import jax
 
 MODES = ("compiled", "interpret", "xla")
 ENV_VAR = "SNS_KERNEL_MODE"
-CACHE_ENV_VAR = "SNS_KERNEL_CACHE"
 
 #: Backends on which a non-interpret pallas_call can actually compile.
 ACCELERATOR_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
@@ -75,6 +79,12 @@ def accel_only(backend: Optional[str], shape: Tuple[int, ...],
                dtype: Any) -> bool:
     """Predicate: accelerator backends only (no CPU Mosaic/Triton)."""
     return backend in ACCELERATOR_BACKENDS
+
+
+def never(backend: Optional[str], shape: Tuple[int, ...],
+          dtype: Any) -> bool:
+    """Preference: never chosen by auto mode (forced modes only)."""
+    return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,9 +249,10 @@ def resolve(op: str, *, mode: Optional[str] = None,
                 f"op {op!r} mode {m!r} is not supported on backend "
                 f"{backend!r} for shape {tuple(shape)} dtype {dtype}")
         return impl
+    on_accel = backend in ACCELERATOR_BACKENDS
     for cand in MODES:  # compiled -> interpret -> xla
         impl = impls.get(cand)
-        if impl is None:
+        if impl is None or (cand == "interpret" and on_accel):
             continue
         if impl.prefer(backend, tuple(shape), dtype) \
                 and impl.supported(backend, tuple(shape), dtype):
@@ -268,7 +279,7 @@ _TILE_TABLE: Dict[str, Dict[str, Dict[str, int]]] = {
                    "gpu": {"block_items": 1024},
                    "*": {"block_items": 1024}},
     "knn_dist_tiles": {"*": {}},     # blocks are data-shape-determined
-    "tsne_step": {"tpu": {"block": 512},
+    "tsne_step": {"tpu": {"block": 128},
                   "gpu": {"block": 256},
                   "*": {"block": 256}},
     "segment_reduce": {"tpu": {"rows_per_block": 256, "edge_chunk": 512},
@@ -286,56 +297,46 @@ def shape_bucket(shape: Tuple[int, ...]) -> str:
     return "x".join(parts) if parts else "scalar"
 
 
-def _cache_path(path: Optional[str] = None) -> str:
-    if path:
-        return path
-    env = os.environ.get(CACHE_ENV_VAR)
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "sns_kernel_autotune.json")
-
-
-def load_autotune_cache(path: Optional[str] = None) -> Dict[str, Dict]:
-    p = _cache_path(path)
+def load_autotune_cache(path: str) -> Dict[str, Dict]:
     try:
-        with open(p, "r", encoding="utf-8") as fh:
+        with open(path, "r", encoding="utf-8") as fh:
             data = json.load(fh)
     except (OSError, ValueError):
         return {}
     return data if isinstance(data, dict) else {}
 
 
-def record_autotune(op: str, params: Dict[str, int], *,
-                    backend: Optional[str] = None, bucket: str = "",
-                    path: Optional[str] = None) -> str:
-    """Persist an autotune winner; returns the cache key written."""
+def record_autotune(op: str, params: Dict[str, int], *, path: str,
+                    backend: Optional[str] = None,
+                    bucket: str = "") -> str:
+    """Persist an autotune winner to the JSON file ``path``; returns the
+    cache key written."""
     backend = backend or jax.default_backend()
     key = f"{backend}/{op}/{bucket or '*'}"
-    p = _cache_path(path)
-    cache = load_autotune_cache(p)
+    cache = load_autotune_cache(path)
     cache[key] = dict(params)
-    os.makedirs(os.path.dirname(p) or ".", exist_ok=True)
-    tmp = p + ".tmp"
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    tmp = path + ".tmp"
     with open(tmp, "w", encoding="utf-8") as fh:
         json.dump(cache, fh, indent=2, sort_keys=True)
-    os.replace(tmp, p)
+    os.replace(tmp, path)
     return key
 
 
 def tile_params(op: str, *, backend: Optional[str] = None,
                 shape: Tuple[int, ...] = None,
                 cache_path: Optional[str] = None) -> Dict[str, int]:
-    """Tile sizes for ``op``: autotuned winner if cached, else the table.
-
-    Lookup order: exact ``backend/op/bucket`` autotune entry, then the
-    backend's wildcard-bucket entry, then the static table row for the
-    backend, then the table's "*" row.
-    """
+    """Tile sizes for ``op`` from the committed table (backend row over
+    the "*" row).  Only an explicit ``cache_path`` layers autotune
+    winners on top: its ``backend/op/*`` entry, then the exact
+    ``backend/op/bucket`` entry.  Nothing outside the checkout is read
+    unless a caller names it."""
     backend = backend or jax.default_backend()
     table = _TILE_TABLE.get(op, {})
     base = dict(table.get("*", {}))
     base.update(table.get(backend, {}))
+    if cache_path is None:
+        return base
     cache = load_autotune_cache(cache_path)
     for key in (f"{backend}/{op}/*",
                 f"{backend}/{op}/{shape_bucket(tuple(shape))}"
@@ -345,11 +346,11 @@ def tile_params(op: str, *, backend: Optional[str] = None,
     return base
 
 
-def autotune_op(op: str, candidates, measure, *,
-                backend: Optional[str] = None, bucket: str = "",
-                cache_path: Optional[str] = None) -> Dict[str, int]:
+def autotune_op(op: str, candidates, measure, *, cache_path: str,
+                backend: Optional[str] = None,
+                bucket: str = "") -> Dict[str, int]:
     """Empirical autotune: time ``measure(params)`` (seconds) for each
-    candidate dict, persist the winner, return it.  Candidates that raise
+    candidate dict, persist the winner to ``cache_path``, return it.  Candidates that raise
     (e.g. a block size that exceeds VMEM) are skipped; all failing is an
     error."""
     backend = backend or jax.default_backend()
@@ -364,6 +365,6 @@ def autotune_op(op: str, candidates, measure, *,
     if best is None:
         raise KernelUnavailableError(
             f"autotune for op {op!r} on {backend!r}: every candidate failed")
-    record_autotune(op, best, backend=backend, bucket=bucket,
-                    path=cache_path)
+    record_autotune(op, best, path=cache_path, backend=backend,
+                    bucket=bucket)
     return best

@@ -25,15 +25,20 @@ Numerics: a direct per-row sum and the cumsum-difference reassociate
 floating-point addition differently, so bitwise equality with
 ``coo.segment_reduce`` holds exactly when the additions are exact (e.g.
 integer-valued fp32 payloads below 2²⁴ — the kernel tests pin bit-for-bit
-there) and to ~1e-6 relative otherwise.  The cumsum path remains the CPU
-default; this kernel registers as the accelerator-preferred path (its
-``prefer`` predicate declines CPU in auto mode, while forced
-``mode="interpret"`` still runs it anywhere for CI coverage).
+there) and to ~1e-6 relative otherwise.
 
-VMEM note: v1 keeps the whole (E, D) payload resident per grid step
-(full-array BlockSpec).  That bounds compiled use to edge lists that fit
-VMEM (~10⁶ × 2 fp32 at 16 MiB); streaming the spans via explicit HBM DMA
-is the follow-up once real hardware is in the loop.
+Dispatch: auto mode never picks this kernel (``prefer=never`` on both
+Pallas tiers), so the cumsum path is the default on every backend until
+a chip measurement shows the kernel ahead; forcing ``mode="compiled"``
+or ``mode="interpret"`` still runs it.
+
+VMEM note: v1 keeps the whole (E, D) payload and the (N+1, 1) bounds
+column resident per grid step (full-array BlockSpecs, each padded to 128
+lanes).  ``fits_vmem`` is the compiled tier's ``supported`` gate: shapes
+whose resident windows exceed ``VMEM_BUDGET`` are refused with
+``KernelUnavailableError`` instead of failing inside the TPU compiler
+(at N = 4096, E = 122,880 the windows alone need ~60 MiB against a
+16 MiB scoped limit).  Streaming the spans from HBM is the follow-up.
 """
 from __future__ import annotations
 
@@ -137,6 +142,36 @@ def segment_reduce_xla(vals: jnp.ndarray, bounds: jnp.ndarray
 
 # -- registry wiring --------------------------------------------------------
 
+#: Resident-window budget the compiled tier accepts (the v5e scoped-VMEM
+#: default is 16 MiB; windows at this size compile for ``v5e``, see
+#: tests/test_tpu_compile.py).
+VMEM_BUDGET = 16 << 20
+
+
+def vmem_bytes(n_rows: int, n_edges: int, width: int, *,
+               rows_per_block: int, edge_chunk: int) -> int:
+    """Bytes of the resident VMEM windows the compiled kernel needs for
+    ``n_rows`` output rows and an (``n_edges``, ``width``) payload: the
+    guard-padded payload plus the bounds column, each rounded to (8, 128)
+    f32/int32 tiles."""
+    rows = min(rows_per_block, max(n_rows, 1))
+    np1 = -(-n_rows // rows) * rows + 1
+    ep = n_edges + (-n_edges) % edge_chunk + edge_chunk
+    lanes = 128 * -(-width // 128)
+    return 4 * lanes * (-(-ep // 8) * 8) + 4 * 128 * (-(-np1 // 8) * 8)
+
+
+def fits_vmem(backend, shape, dtype) -> bool:
+    """Compiled-tier ``supported`` gate.  ``shape`` is the op's
+    ``(n_rows, n_edges, width)`` as ``coo.segment_reduce`` reports it."""
+    if not registry.accel_only(backend, shape, dtype):
+        return False
+    if len(shape) != 3:
+        return True
+    tiles = registry.tile_params("segment_reduce", backend=backend)
+    return vmem_bytes(*shape, **tiles) <= VMEM_BUDGET
+
+
 def _run(interpret: bool):
     def fn(vals, bounds, *, rows_per_block: int = 128,
            edge_chunk: int = 256):
@@ -146,11 +181,10 @@ def _run(interpret: bool):
     return fn
 
 
-registry.register("segment_reduce", "compiled")(_run(False))
-# prefer declines CPU so the cumsum path stays the CPU default in auto
-# mode; forcing mode="interpret" still runs the kernel anywhere.
+registry.register("segment_reduce", "compiled", supported=fits_vmem,
+                  prefer=registry.never)(_run(False))
 registry.register("segment_reduce", "interpret",
-                  prefer=registry.accel_only)(_run(True))
+                  prefer=registry.never)(_run(True))
 
 
 @registry.register("segment_reduce", "xla")

@@ -55,6 +55,12 @@ def _pair_mask(bi, bj, block, n_valid):
     return (gi != gj) & (gi < n_valid) & (gj < n_valid)
 
 
+# Scalar accumulators live in one (8, 128) f32 tile: Mosaic cannot store
+# a scalar to VMEM, so each partial sum is broadcast over the tile (Z) or
+# over one lane column (KL parts) and read back from row 0 afterwards.
+_ACC_TILE = (8, 128)
+
+
 def _z_kernel(y_i_ref, y_j_ref, z_ref, *, block: int, n_valid: int):
     @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _init():
@@ -63,7 +69,7 @@ def _z_kernel(y_i_ref, y_j_ref, z_ref, *, block: int, n_valid: int):
     d2 = _sq_dists(y_i_ref[...], y_j_ref[...])
     num = 1.0 / (1.0 + d2)
     mask = _pair_mask(pl.program_id(0), pl.program_id(1), block, n_valid)
-    z_ref[0, 0] += jnp.sum(jnp.where(mask, num, 0.0))
+    z_ref[...] += jnp.sum(jnp.where(mask, num, 0.0))
 
 
 def _force_kernel(x_i_ref, x_j_ref, y_i_ref, y_j_ref, s_i_ref, s_j_ref,
@@ -97,10 +103,13 @@ def _force_kernel(x_i_ref, x_j_ref, y_i_ref, y_j_ref, s_i_ref, s_j_ref,
     out_ref[...] += 4.0 * (
         jnp.sum(pq, axis=1, keepdims=True) * y_i
         - jnp.dot(pq, y_j, preferred_element_type=jnp.float32))
-    kl_ref[0, 0] += jnp.sum(
+    kl_a = jnp.sum(
         jnp.where(pe > 0, pe * jnp.log(jnp.maximum(pe, 1e-37)), 0.0))
-    kl_ref[0, 1] += jnp.sum(
+    kl_b = jnp.sum(
         jnp.where(pe > 0, pe * jnp.log(jnp.maximum(num, 1e-37)), 0.0))
+    lane = jax.lax.broadcasted_iota(jnp.int32, _ACC_TILE, 1)
+    kl_ref[...] += jnp.where(lane == 0, kl_a,
+                             jnp.where(lane == 1, kl_b, 0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "n_valid", "interpret"))
@@ -118,8 +127,8 @@ def tsne_z(y: jnp.ndarray, *, block: int = 256, n_valid: int = None,
             pl.BlockSpec((block, y.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((block, y.shape[1]), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=pl.BlockSpec(_ACC_TILE, lambda i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(_ACC_TILE, jnp.float32),
         interpret=interpret,
     )(y, y)
     return z[0, 0]
@@ -148,7 +157,7 @@ def tsne_forces(x: jnp.ndarray, y: jnp.ndarray, stats: jnp.ndarray,
     scal = jnp.stack([z.astype(jnp.float32),
                       jnp.asarray(exaggeration, jnp.float32)]).reshape(1, 2)
 
-    return pl.pallas_call(
+    forces, kl = pl.pallas_call(
         functools.partial(_force_kernel, block=block, n_valid=n_valid),
         grid=(nb, nb),
         in_specs=[
@@ -162,14 +171,15 @@ def tsne_forces(x: jnp.ndarray, y: jnp.ndarray, stats: jnp.ndarray,
         ],
         out_specs=(
             pl.BlockSpec((block, y.shape[1]), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
+            pl.BlockSpec(_ACC_TILE, lambda i, j: (0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((n, y.shape[1]), jnp.float32),
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
+            jax.ShapeDtypeStruct(_ACC_TILE, jnp.float32),
         ),
         interpret=interpret,
     )(x, x, y, y, stats, stats, scal)
+    return forces, kl[0:1, 0:2]
 
 
 # -- XLA reference + registry wiring ----------------------------------------
@@ -212,8 +222,18 @@ def tsne_step_xla(x: jnp.ndarray, y: jnp.ndarray, stats: jnp.ndarray,
     return forces, kl_parts, z
 
 
+#: Largest tile the compiled force kernel runs.  Its (B, B) f32
+#: temporaries overflow the 16 MiB scoped VMEM above B = 128 (B = 256
+#: needs a raised limit and ~50 s of compile; B = 512 does not finish
+#: compiling for ``v5e``), so larger requested blocks that are multiples
+#: of it run as 128-row tiles over the same padded arrays.
+COMPILED_MAX_BLOCK = 128
+
+
 def _step_mode(interpret: bool):
     def fn(x, y, stats, exaggeration, *, block: int = 256, n_valid=None):
+        if not interpret and block % COMPILED_MAX_BLOCK == 0:
+            block = COMPILED_MAX_BLOCK
         z = tsne_z(y, block=block, n_valid=n_valid, interpret=interpret)
         f, kl_parts = tsne_forces(
             x, y, stats, z, jnp.asarray(exaggeration, jnp.float32),

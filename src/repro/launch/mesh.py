@@ -18,15 +18,25 @@ import jax
 import numpy as np
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules and
+    ``with_sharding_constraint`` calls in this stack name mesh axes in
+    ``NamedSharding`` specs, which ``Explicit`` axes (the installed
+    JAX's default) refuse."""
+    axes = tuple(axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
     """Arbitrary small mesh for tests/examples on host devices."""
-    return jax.make_mesh(shape, tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

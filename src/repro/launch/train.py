@@ -45,6 +45,7 @@ def main() -> None:
     from repro.configs import get_config
     from repro.data import zipf_token_stream
     from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_mesh
     from repro.train.steps import TrainStepConfig
     from repro.train.trainer import Trainer, TrainerConfig
 
@@ -52,7 +53,7 @@ def main() -> None:
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
         names = ("pod", "data", "model")[-len(shape):]
-        mesh = jax.make_mesh(shape, names)
+        mesh = make_mesh(shape, names)
         dp = tuple(a for a in names if a != "model")
         shlib.set_activation_sharding(mesh, dp, "model",
                                       act_mode=args.act_mode)

@@ -86,10 +86,11 @@ _SMALL_MESH_SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.configs import get_config
     from repro.launch import sharding as shlib
+    from repro.launch.mesh import make_host_mesh
     from repro.train.steps import (TrainStepConfig, make_train_step,
                                    make_batch_specs, train_state_specs)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("jamba-v0.1-52b", smoke=True)
     pol = shlib.ShardingPolicy(act_mode="seq_tp")
     shlib.set_activation_sharding(mesh, ("pod", "data"), "model",

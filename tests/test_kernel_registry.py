@@ -93,6 +93,50 @@ class TestResolutionOrder:
             registry.resolve(name, backend="cpu")
 
 
+class TestAcceleratorAuto:
+    """On an accelerator, auto mode never lands on the Pallas
+    interpreter: a compiled tier that declines falls through to XLA."""
+
+    def test_declined_compiled_falls_through_to_xla(self, fake_op):
+        name, install = fake_op
+        install("compiled", supported=lambda b, s, d: False)
+        install("interpret")
+        install("xla")
+        assert registry.resolve(name, backend="tpu").mode == "xla"
+        assert registry.resolve(name, backend="gpu").mode == "xla"
+        # the interpreter stays the CPU's auto pick and stays forceable
+        assert registry.resolve(name, backend="cpu").mode == "interpret"
+        assert registry.resolve(name, mode="interpret",
+                                backend="tpu").mode == "interpret"
+
+    def test_interpret_only_op_has_no_auto_pick_on_tpu(self, fake_op):
+        name, install = fake_op
+        install("interpret")
+        with pytest.raises(registry.KernelUnavailableError):
+            registry.resolve(name, backend="tpu")
+
+    @pytest.mark.parametrize("op", ["cic_splat", "cic_gather",
+                                    "knn_dist_tiles", "tsne_step",
+                                    "segment_reduce"])
+    def test_builtin_ops_never_auto_interpret_on_tpu(self, op):
+        assert registry.resolve(op, backend="tpu").mode != "interpret"
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu", "gpu"])
+    def test_segment_reduce_auto_is_the_cumsum_path(self, backend):
+        shape = (4096, 122_880, 2)
+        assert registry.resolve("segment_reduce", backend=backend,
+                                shape=shape).mode == "xla"
+
+    def test_segment_reduce_compiled_refuses_shapes_over_vmem(self):
+        with pytest.raises(registry.KernelUnavailableError,
+                           match="segment_reduce"):
+            registry.resolve("segment_reduce", mode="compiled",
+                             backend="tpu", shape=(4096, 122_880, 2))
+        assert registry.resolve("segment_reduce", mode="compiled",
+                                backend="tpu",
+                                shape=(1024, 16_384, 2)).mode == "compiled"
+
+
 class TestModePrecedence:
     def test_explicit_beats_override_and_env(self, fake_op, monkeypatch):
         name, install = fake_op
@@ -447,11 +491,22 @@ def test_ann_kernel_mode_forces_distance_tier():
     np.testing.assert_array_equal(np.asarray(ref_i), np.asarray(ref_x))
 
 
+def test_ann_pallas_tile_follows_the_registry():
+    """AnnConfig(tile="pallas") with no interpret flag lets the registry
+    pick the kernel tier (the interpreter on CPU) and matches XLA."""
+    x = jnp.asarray(np.random.default_rng(11).normal(
+        size=(128, 4)).astype(np.float32))
+    assert ann_mod.AnnConfig().interpret is None
+    got, _ = ann_mod.ann_knn_graph(x, 4, ann_mod.AnnConfig(tile="pallas"))
+    ref, _ = ann_mod.ann_knn_graph(x, 4, ann_mod.AnnConfig(tile="xla"))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
 # --------------------------------------------------------- tile params
 def test_tile_params_table_and_cache(tmp_path):
     p = registry.tile_params("cic_splat", backend="cpu")
     assert p["block_items"] == 1024
-    assert registry.tile_params("tsne_step", backend="tpu")["block"] == 512
+    assert registry.tile_params("tsne_step", backend="tpu")["block"] == 128
     cache = tmp_path / "tune.json"
     registry.record_autotune("cic_splat", {"block_items": 2048},
                              backend="cpu", bucket="65536x2",
@@ -462,6 +517,23 @@ def test_tile_params_table_and_cache(tmp_path):
     other = registry.tile_params("cic_splat", backend="cpu",
                                  shape=(100, 2), cache_path=str(cache))
     assert other["block_items"] == 1024        # different bucket -> table
+
+
+def test_tile_params_read_only_the_committed_table(tmp_path, monkeypatch):
+    """No environment variable or file under HOME reaches the program's
+    tile sizes: only an explicit cache path does."""
+    stray = tmp_path / "tune.json"
+    registry.record_autotune("cic_splat", {"block_items": 4096},
+                             path=str(stray), backend="cpu")
+    home_cache = tmp_path / ".cache" / "sns_kernel_autotune.json"
+    home_cache.parent.mkdir()
+    home_cache.write_text(stray.read_text())
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("SNS_KERNEL_CACHE", str(stray))
+    assert registry.tile_params("cic_splat", backend="cpu",
+                                shape=(1000, 3))["block_items"] == 1024
+    assert registry.tile_params("cic_splat", backend="cpu",
+                                cache_path=str(stray))["block_items"] == 4096
 
 
 def test_shape_bucket():

@@ -385,10 +385,10 @@ def test_sharded_tsne_stage_jaxpr_pins_collectives_and_scatters(mesh8):
 
     sharded = jax.make_jaxpr(functools.partial(
         tsne._sparse_stage_mesh, cfg=cfg, count=4, grid_size=32,
-        interpret=True, mesh=mesh8, n=n))(state(n_pad), kls, ssp, it0)
+        mesh=mesh8, n=n))(state(n_pad), kls, ssp, it0)
     single = jax.make_jaxpr(functools.partial(
-        tsne._sparse_stage, cfg=cfg, count=4, grid_size=32,
-        interpret=True))(state(n), kls, sp, it0)
+        tsne._sparse_stage, cfg=cfg, count=4, grid_size=32))(
+            state(n), kls, sp, it0)
 
     for prim in ("scatter-add", "scatter", "scatter-mul", "scatter-max"):
         assert count_primitive(sharded.jaxpr, prim) == \
@@ -455,7 +455,7 @@ def test_cancer_1m_config_constructs_sharded_stage(mesh8):
     state = tsne.TsneState(z, z, jnp.ones((n_pad, 2)))
     jaxpr = jax.make_jaxpr(functools.partial(
         tsne._sparse_stage_mesh, cfg=tc, count=tc.adaptive_interval,
-        grid_size=tc.grid_size, interpret=True, mesh=mesh8, n=n))(
+        grid_size=tc.grid_size, mesh=mesh8, n=n))(
             state, jnp.zeros((tc.n_iter,)), ssp, jnp.asarray(0, jnp.int32))
     assert count_primitive(jaxpr.jaxpr, "all_gather") == 1
     assert count_primitive(jaxpr.jaxpr, "psum") == 5
