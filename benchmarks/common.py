@@ -4,7 +4,6 @@ scaling benches to report memory trajectories past the point where
 allocation would OOM)."""
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import time
@@ -12,23 +11,6 @@ from typing import Callable, List, Optional
 
 import jax
 import numpy as np
-
-
-@contextlib.contextmanager
-def maybe_trace(trace_dir: Optional[str], label: str = "bench"):
-    """Opt-in profiler capture: with ``trace_dir`` set, the wrapped
-    region runs under ``jax.profiler.trace`` and the TensorBoard/Perfetto
-    artifacts land in ``trace_dir/label`` (one subdirectory per bench so
-    a multi-bench run keeps captures separate).  ``trace_dir=None`` is a
-    no-op with zero overhead — the default for every CI and baseline
-    run, since profiling perturbs the timings it wraps."""
-    if not trace_dir:
-        yield
-        return
-    out = os.path.join(trace_dir, label)
-    os.makedirs(out, exist_ok=True)
-    with jax.profiler.trace(out):
-        yield
 
 
 def repo_root_json(name: str) -> str:

@@ -125,14 +125,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="reduced sizes (CI-friendly)")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--trace", default=None, metavar="DIR",
-                    help="capture a jax.profiler trace of each bench's "
-                         "timed region into DIR/<bench> (opt-in; "
-                         "profiling overhead perturbs timings, so never "
-                         "set this for baseline runs)")
     args = ap.parse_args()
-
-    from benchmarks.common import maybe_trace
 
     jobs = build_jobs(args.fast)
     names = [name for name, _, _ in jobs]
@@ -146,8 +139,7 @@ def main() -> None:
         mod = _load(module)
         t0 = time.time()
         try:
-            with maybe_trace(args.trace, name):
-                print(runner(mod))
+            print(runner(mod))
             print(f"# [{name} done in {time.time() - t0:.1f}s]\n",
                   flush=True)
         except Exception as e:                               # noqa: BLE001

@@ -103,6 +103,7 @@ def empty(k: int) -> Candidates:
         mask=jnp.zeros((k,), bool))
 
 
+@jax.named_scope("sns.fold.sort")
 def sorted_runs(key_hi: jnp.ndarray, key_lo: jnp.ndarray,
                 values: Optional[jnp.ndarray] = None,
                 mask: Optional[jnp.ndarray] = None,
@@ -255,6 +256,7 @@ def _searchsorted_pair(b_hi: jnp.ndarray, b_lo: jnp.ndarray,
     return lo
 
 
+@jax.named_scope("sns.fold.merge")
 def merge_runs(pool: Candidates, runs: KeyRuns, k: int
                ) -> Tuple[Candidates, jnp.ndarray]:
     """Sort-free bounded reservoir merge: the streaming-fold hot path.

@@ -15,6 +15,7 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -148,6 +149,7 @@ def cell_center(grid: GridSpec, coords: jnp.ndarray) -> jnp.ndarray:
     return jnp.asarray(grid.lo_arr) + (coords.astype(jnp.float32) + 0.5) * cs
 
 
+@jax.named_scope("sns.fold.keys")
 def points_to_keys(grid: GridSpec, points: jnp.ndarray) -> u64.U64:
     return pack(grid, quantize(grid, points))
 

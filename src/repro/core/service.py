@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import geo
 from repro.core import heavy_hitters as hh_mod
 from repro.core import neighbors, pipeline, replicas
@@ -215,12 +216,14 @@ class SnsService:
         """
         if pipeline._is_points_array(chunks):
             chunks = [chunks]
-        before = float(self.state.count)     # syncs any in-flight fold
+        with obs.span("sns.update.wait"):
+            before = float(self.state.count)     # syncs any in-flight fold
         t0 = time.perf_counter()
         self.state = stream_mod.ingest_all(
             self.state, self.grid, pipeline._chunk_stream(chunks),
             self.cfg.ingest_chunk, superbatch=self.cfg.ingest_superbatch)
-        absorbed = float(self.state.count) - before   # blocks on the fold
+        with obs.span("sns.update.wait"):
+            absorbed = float(self.state.count) - before   # blocks on the fold
         dt = time.perf_counter() - t0
         self._pending += absorbed
         return {"points": absorbed, "seconds": dt,
@@ -457,7 +460,9 @@ class SnsService:
         ``coverage`` / ``lost_shards`` / ``hh_error_bound`` reflect any
         degradation absorbed by :meth:`update_shards`; ``last_refresh``
         records the most recent refresh outcome (including failures the
-        transactional swap rolled back)."""
+        transactional swap rolled back); ``ingest`` holds the
+        process-wide host spans and counters of the ingest path
+        (``repro.obs.snapshot()``: ``sns.ingest.*``, ``sns.update.wait``)."""
         c = self._cache
         return {
             "serving": c is not None,
@@ -481,6 +486,7 @@ class SnsService:
             "refreshes": self._refreshes,
             "refresh_failures": self._refresh_failures,
             "last_refresh": self._last_refresh,
+            "ingest": obs.snapshot(),
         }
 
     # --------------------------------------------------------- transform

@@ -108,6 +108,7 @@ def update(sk: CountSketch, key_hi: jnp.ndarray, key_lo: jnp.ndarray,
     return sk._replace(table=flat.reshape(sk.table.shape))
 
 
+@jax.named_scope("sns.fold.scatter")
 def update_runs(sk: CountSketch, runs: KeyRuns) -> CountSketch:
     """Scatter pre-deduped sorted key runs into the table — the bulk path.
 

@@ -21,7 +21,13 @@ feeds the same deduped runs to both consumers — the sketch scatter
 (``sketch.update_runs``) and the reservoir merge
 (``candidates.merge_runs``, a binary-search sorted merge against the
 key-sorted reservoir; no second sort).  Exactly one sort primitive per
-chunk step, jaxpr-regression-tested in tests/test_fused_ingest.py.
+chunk step, jaxpr-regression-tested in tests/test_fused_ingest.py.  The
+four stages carry a ``jax.named_scope`` in the functions that do the work,
+so every caller's compiled ops are named by stage in their ``op_name``:
+``sns.fold.keys`` (``quantize.points_to_keys``), ``sns.fold.sort``
+(``candidates.sorted_runs``), ``sns.fold.scatter`` (``sketch.update_runs``)
+and ``sns.fold.merge`` (``candidates.merge_runs``, also under
+:func:`merge_states`).
 
 The reservoir invariant: a key held by the reservoir accumulates its
 *exact* count, so while the number of distinct keys seen stays ≤ L the
@@ -62,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import candidates as cand_mod
 from repro.core import hashing, quantize, sketch as sketch_mod
 from repro.core.candidates import Candidates
@@ -226,30 +233,48 @@ def ingest_all(state: IngestState, grid: GridSpec,
     """Drive the jitted fold over a whole (host-side) chunk stream.
 
     ``superbatch`` > 1 enables the throughput path: B rechunked blocks are
-    stacked per dispatch (:func:`ingest_superbatch`) and the host→device
-    transfer of superbatch b+1 is enqueued while b computes — JAX dispatch
-    is asynchronous, so ``device_put`` of the next batch overlaps the
-    running scan (double buffering).  ``superbatch=1`` is the per-chunk
-    low-latency path."""
-    if superbatch <= 1:
-        for pts, mask in rechunk(chunks, chunk_size):
-            state = ingest_chunk(state, jnp.asarray(pts), jnp.asarray(mask),
-                                 grid=grid)
-        return state
+    stacked per dispatch (:func:`ingest_superbatch`); ``superbatch=1``
+    folds one chunk per dispatch (:func:`ingest_chunk`).  Either way the
+    host→device transfer of batch b+1 is enqueued while b computes — JAX
+    dispatch is asynchronous, so ``device_put`` of the next batch overlaps
+    the running fold (double buffering).
 
-    def _put(batch):
+    Host spans (``repro.obs``): ``sns.ingest.assemble`` (rechunk and
+    stack one batch), ``sns.ingest.put`` (its ``device_put``),
+    ``sns.ingest.dispatch`` (the fold's call; a long one compiles).
+    Counters: ``sns.ingest.points`` (valid rows), ``sns.ingest.rows``
+    (rows dispatched, padding included), ``sns.ingest.superbatches``
+    (dispatches; a lone chunk when ``superbatch`` is 1) and
+    ``sns.ingest.put_bytes``."""
+    if superbatch <= 1:
+        batches = rechunk(chunks, chunk_size)
+        fold = ingest_chunk
+    else:
+        batches = _superbatches(rechunk(chunks, chunk_size), superbatch)
+        fold = ingest_superbatch
+
+    def _next():
+        with obs.span("sns.ingest.assemble"):
+            batch = next(batches, None)
         if batch is None:
             return None
-        return jax.device_put(batch[0]), jax.device_put(batch[1])
+        pts, mask = batch
+        with obs.span("sns.ingest.put"):
+            out = jax.device_put(pts), jax.device_put(mask)
+        obs.count("sns.ingest.put_bytes", pts.nbytes + mask.nbytes)
+        return out + (int(np.count_nonzero(mask)), mask.size)
 
-    batches = _superbatches(rechunk(chunks, chunk_size), superbatch)
-    nxt = _put(next(batches, None))
+    nxt = _next()
     while nxt is not None:
-        cur, nxt = nxt, None
-        state = ingest_superbatch(state, cur[0], cur[1], grid=grid)
+        pts, mask, points, rows = nxt
+        with obs.span("sns.ingest.dispatch"):
+            state = fold(state, pts, mask, grid=grid)
+        obs.count("sns.ingest.points", points)
+        obs.count("sns.ingest.rows", rows)
+        obs.count("sns.ingest.superbatches")
         # state is dispatched asynchronously — assembling + transferring
-        # the next superbatch here overlaps the device-side compute
-        nxt = _put(next(batches, None))
+        # the next batch here overlaps the device-side compute
+        nxt = _next()
     return state
 
 
