@@ -8,8 +8,9 @@ stream (same generator as bench_ingest_scaling):
   raw-chunk for the reservoir — every chunk pays two lexsorts over
   overlapping key material, one of them over the whole L-entry pool.
 * ``fused``    — one ``sorted_runs`` per chunk feeds both the sketch
-  scatter and the sort-free ``merge_runs`` (binary-search sorted merge
-  against the key-sorted reservoir); still one dispatch per chunk.
+  scatter and ``merge_runs`` (two stable sorts of the key-sorted
+  reservoir with the deduped runs, keys and counts carried as payload);
+  still one dispatch per chunk.
 * ``fused_superbatch`` — the fused fold inside ``ingest_superbatch``'s
   donated ``lax.scan`` (B chunks per dispatch) driven by the
   double-buffered ``ingest_all`` (device_put of batch b+1 overlaps the

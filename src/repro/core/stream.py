@@ -19,11 +19,12 @@ Hot-path structure (the fused single-sort fold): ``ingest_step`` sorts and
 run-length-encodes the chunk's keys ONCE (``candidates.sorted_runs``) and
 feeds the same deduped runs to both consumers — the sketch scatter
 (``sketch.update_runs``) and the reservoir merge
-(``candidates.merge_runs``, a binary-search sorted merge against the
-key-sorted reservoir; no second sort).  Exactly one sort primitive per
-chunk step, jaxpr-regression-tested in tests/test_fused_ingest.py.  The
-four stages carry a ``jax.named_scope`` in the functions that do the work,
-so every caller's compiled ops are named by stage in their ``op_name``:
+(``candidates.merge_runs``, two stable multi-operand sorts of the
+key-sorted reservoir with the runs, no gather).  One sort of the chunk's
+keys and two in the merge per chunk step, jaxpr-regression-tested in
+tests/test_fused_ingest.py.  The four stages carry a ``jax.named_scope``
+in the functions that do the work, so every caller's compiled ops are
+named by stage in their ``op_name``:
 ``sns.fold.keys`` (``quantize.points_to_keys``), ``sns.fold.sort``
 (``candidates.sorted_runs``), ``sns.fold.scatter`` (``sketch.update_runs``)
 and ``sns.fold.merge`` (``candidates.merge_runs``, also under
@@ -81,10 +82,11 @@ class IngestState(NamedTuple):
     scans, donates, and psums like any other JAX state.
 
     ``cands`` is maintained KEY-SORTED (live keys ascending, padding last)
-    — the invariant that lets ``candidates.merge_runs`` merge without
-    sorting.  ``evict_max`` is the space-saving diagnostic: the largest
-    exact count ever evicted from the reservoir (0 while the distinct-key
-    universe fits in the pool, i.e. while the reservoir is exact)."""
+    — the invariant that lets ``candidates.merge_runs`` take it as one
+    sorted input of its merge.  ``evict_max`` is the space-saving
+    diagnostic: the largest exact count ever evicted from the reservoir (0
+    while the distinct-key universe fits in the pool, i.e. while the
+    reservoir is exact)."""
     sketch: CountSketch     # (R, C) table + hash params
     cands: Candidates       # (L,) bounded candidate reservoir, key-sorted
     count: jnp.ndarray      # () float32 — items ingested so far
@@ -283,7 +285,7 @@ def merge_states(a: IngestState, b: IngestState) -> IngestState:
     (the paper's same-hash-functions contract — checked by table shape;
     value equality is the caller's responsibility, exactly as in
     ``sketch.merge``): sketch tables add, candidate reservoirs combine
-    through the sort-free sorted merge (``b``'s reservoir re-keyed as
+    through the streaming fold's merge (``b``'s reservoir re-keyed as
     runs via ``candidates.runs_from_candidates``), counts add, and the
     eviction watermarks max — including anything evicted by THIS merge,
     so the space-saving diagnostic stays a true upper bound.
@@ -297,9 +299,9 @@ def merge_states(a: IngestState, b: IngestState) -> IngestState:
         raise ValueError(
             f"cannot merge sketches of different geometry: "
             f"{a.sketch.table.shape} vs {b.sketch.table.shape}")
-    # merge_runs' clamped gathers assume jnp semantics — host-side states
-    # (device_get'd shard results, loaded checkpoints) arrive as numpy,
-    # where an out-of-range index raises instead of clamping
+    # host-side states (device_get'd shard results, loaded checkpoints)
+    # arrive as numpy: lift every leaf to a device array, so the merge
+    # below runs the same jnp code on the same inputs as the fold
     a = jax.tree_util.tree_map(jnp.asarray, a)
     b = jax.tree_util.tree_map(jnp.asarray, b)
     runs = cand_mod.runs_from_candidates(b.cands)

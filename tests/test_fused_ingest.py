@@ -3,21 +3,25 @@
 The PR-3 throughput refactor replaced the two-sort chunk fold
 (``sketch.update_sorted`` + ``candidates.merge_topk`` re-sorting pool ∪
 raw-chunk) with one ``candidates.sorted_runs`` per chunk feeding both the
-sketch scatter (``sketch.update_runs``) and a sort-free sorted-merge
-reservoir update (``candidates.merge_runs``, key-sorted carried
-invariant).  The fused path is a re-association of the same exact-integer
-adds, so it must be *bit-identical* to the legacy path:
+sketch scatter (``sketch.update_runs``) and a sorted-merge reservoir
+update (``candidates.merge_runs``, key-sorted carried invariant, two
+stable sorts over the reservoir and the runs).  The fused path is a
+re-association of the same exact-integer adds, so it must be
+*bit-identical* to the legacy path:
 
 * sketch tables equal exactly;
 * reservoir live (key → count) sets equal exactly (storage order differs
   by design: merge_topk count-descending vs merge_runs key-ascending);
 * heavy hitters extracted from either reservoir equal exactly.
 
-Plus the trace regressions the perf claim rests on: exactly ONE sort
-primitive per chunk step (legacy had two), and the superbatched scan's
-trace is O(1) in the number of stacked chunks.  And the two PR-3
-follow-ups: resumable ingest (save/load round-trip, bit-identical resume)
-and the eviction-watermark space-saving diagnostic.
+Plus the trace regressions the perf claims rest on: ONE sort of the
+chunk's keys per chunk step (legacy had two), a reservoir merge built from
+exactly two stable sorts with no gather and no binary-search loop (on a
+TPU v5e a 2^20-element sort costs ~0.8 ms, the searchsorted gathers it
+replaced ~37 ms a chunk), and the superbatched scan's trace is O(1) in the
+number of stacked chunks.  And two follow-ups of the fused fold:
+resumable ingest (save/load round-trip, bit-identical resume) and the
+eviction-watermark space-saving diagnostic.
 """
 import jax
 import jax.numpy as jnp
@@ -150,6 +154,96 @@ def test_merge_runs_matches_merge_topk_property(universe, masked_tail, seed):
     assert float(evicted) >= 0.0
 
 
+def _sorted_pool(keys, counts, k):
+    """A key-sorted reservoir of capacity k holding (packed key → count)."""
+    keys = np.asarray(keys, np.uint64)
+    order = np.argsort(keys, kind="stable")
+    keys, counts = keys[order], np.asarray(counts, np.float32)[order]
+    pad = k - keys.size
+    return Candidates(
+        key_hi=jnp.asarray(np.concatenate([
+            (keys >> np.uint64(32)).astype(np.uint32),
+            np.full(pad, candidates.INVALID_KEY, np.uint32)])),
+        key_lo=jnp.asarray(np.concatenate([
+            (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+            np.full(pad, candidates.INVALID_KEY, np.uint32)])),
+        count=jnp.asarray(np.concatenate([counts,
+                                          np.zeros(pad, np.float32)])),
+        mask=jnp.asarray(np.arange(k) < keys.size))
+
+
+def _numpy_merge(pool, hi, lo, mask, k):
+    """Exact reference of one reservoir merge: live (key → count) of pool
+    plus the chunk's masked counts; keep the k largest counts, ties to the
+    lower key; return them key-ascending and the largest evicted count."""
+    tot = _cand_dict(pool)
+    packed = (np.asarray(hi, np.uint64) << np.uint64(32)) | \
+        np.asarray(lo, np.uint64)
+    for key in packed[np.asarray(mask)].tolist():
+        tot[key] = tot.get(key, 0.0) + 1.0
+    ranked = sorted(tot.items(), key=lambda kv: (-kv[1], kv[0]))
+    kept = sorted(ranked[:k])
+    evicted = max((c for _, c in ranked[k:]), default=0.0)
+    return kept, evicted
+
+
+@pytest.mark.parametrize("case", [
+    "empty_pool", "masked_chunk", "ties_at_threshold", "pool_below_runs",
+    "run_equals_pool_max"])
+def test_merge_runs_edge_cases(case):
+    """Edge cases of the reservoir merge against ``merge_topk`` and an
+    exact NumPy reference, down to the storage order and the evicted
+    maximum: the output stays key-sorted."""
+    rng = np.random.default_rng(29)
+    n, k = 64, 16
+    pool = candidates.empty(k)
+    hi, lo = _key_stream(rng, n, 24)
+    mask = jnp.ones((n,), bool)
+    if case == "masked_chunk":          # a live pool, nothing arrives
+        pool = _sorted_pool([3, 9, (1 << 32) + 5], [4.0, 1.0, 2.0], k)
+        mask = jnp.zeros((n,), bool)
+    elif case == "ties_at_threshold":   # 64 distinct keys of count 1
+        hi, lo = _key_stream(rng, n, None)
+        pool = _sorted_pool([1000, 1001], [1.0, 1.0], k)
+    elif case == "pool_below_runs":     # k = 5 ≪ the chunk's runs
+        k = 5
+        pool = _sorted_pool([2, 7, 11], [3.0, 1.0, 2.0], k)
+        hi, lo = _key_stream(rng, 256, 40)
+        n = 256
+        mask = jnp.arange(n) < 200
+    elif case == "run_equals_pool_max":  # chunk hits the pool's last live
+        pool = _sorted_pool([1, 4, 20], [2.0, 1.0, 5.0], k)
+        lo = jnp.asarray(np.r_[np.full(8, 20), rng.integers(0, 30, n - 8)]
+                         .astype(np.uint32))
+        hi = jnp.zeros((n,), jnp.uint32)
+    runs = candidates.sorted_runs(hi, lo, mask=mask)
+    fused, evicted = candidates.merge_runs(pool, runs, k)
+    _assert_key_sorted(fused)
+    chunk = Candidates(key_hi=hi, key_lo=lo,
+                       count=jnp.ones((n,), jnp.float32), mask=mask)
+    assert _cand_dict(fused) == _cand_dict(
+        candidates.merge_topk(pool, chunk, k))
+    kept, ref_evicted = _numpy_merge(pool, hi, lo, mask, k)
+    m = np.asarray(fused.mask)
+    assert m.sum() == len(kept) and np.all(m[:len(kept)])
+    packed = (np.asarray(fused.key_hi, np.uint64) << np.uint64(32)) | \
+        np.asarray(fused.key_lo, np.uint64)
+    assert packed[m].tolist() == [key for key, _ in kept]
+    assert np.asarray(fused.count)[m].tolist() == [c for _, c in kept]
+    assert np.all(np.asarray(fused.key_hi)[~m] == candidates.INVALID_KEY)
+    assert np.all(np.asarray(fused.count)[~m] == 0.0)
+    assert float(evicted) == ref_evicted
+
+
+def test_merge_runs_rejects_k_beyond_inputs():
+    """The merge keeps at most pool + runs entries: a larger k is refused
+    at trace time instead of returning a short reservoir."""
+    runs = candidates.sorted_runs(*_key_stream(np.random.default_rng(1),
+                                               8, 4))
+    with pytest.raises(ValueError, match="exceeds pool"):
+        candidates.merge_runs(candidates.empty(4), runs, 13)
+
+
 # ----------------------------------------------------- step-level identity
 @pytest.mark.parametrize("universe,masked_tail,grid", [
     (6, 0, GRID), (6, 100, GRID), (2000, 0, GRID), (None, 0, GRID),
@@ -193,12 +287,24 @@ def _jaxpr_of_step():
                                 jnp.ones((512,), bool))
 
 
+def _merge_jaxpr(assume_hi_zero):
+    """jaxpr of ``merge_runs`` alone, on runs from the one-limb or the
+    two-limb key sort."""
+    hi, lo = _key_stream(np.random.default_rng(0), 512, 40)
+    runs = candidates.sorted_runs(hi, lo, assume_hi_zero=assume_hi_zero)
+    return jax.make_jaxpr(lambda p, r: candidates.merge_runs(p, r, 16))(
+        candidates.empty(16), runs)
+
+
 def test_exactly_one_sort_per_chunk_step():
-    """THE perf claim: the fused step issues exactly one sort primitive
-    (legacy two-sort step: two).  top_k / cumsum / binary search gathers
-    are not sorts."""
+    """The fused step sorts the chunk's keys exactly once (``sorted_runs``;
+    the legacy two-sort step: two); every other sort of the step is one
+    of ``merge_runs``' two stable sorts, so the step holds three."""
     from benchmarks.common import count_primitive
-    assert count_primitive(_jaxpr_of_step().jaxpr, "sort") == 1
+    step_sorts = count_primitive(_jaxpr_of_step().jaxpr, "sort")
+    merge_sorts = count_primitive(_merge_jaxpr(False).jaxpr, "sort")
+    assert merge_sorts == 2
+    assert step_sorts - merge_sorts == 1
 
     state = stream.init(jax.random.key(0), 4, 8, 16)
 
@@ -213,7 +319,8 @@ def test_exactly_one_sort_per_chunk_step():
 def test_superbatch_trace_o1_and_single_sort():
     """The (B, chunk, D) superbatch scan body is traced once: total
     equation count is independent of B, and the whole superbatch jaxpr
-    still contains exactly one sort."""
+    holds three sorts, whatever B: the chunk's single key sort plus the
+    reservoir merge's two."""
     from benchmarks.common import count_eqns, count_primitive
 
     def jaxpr_for(b):
@@ -224,7 +331,19 @@ def test_superbatch_trace_o1_and_single_sort():
 
     j2, j16 = jaxpr_for(2), jaxpr_for(16)
     assert count_eqns(j2.jaxpr) == count_eqns(j16.jaxpr)
-    assert count_primitive(j16.jaxpr, "sort") == 1
+    assert count_primitive(j16.jaxpr, "sort") == 3
+
+
+@pytest.mark.parametrize("assume_hi_zero", [True, False])
+def test_merge_runs_is_two_sorts_no_gather_no_while(assume_hi_zero):
+    """The reservoir merge carries its payload through two stable
+    multi-operand sorts: no gather and no binary-search loop, on both
+    the one-limb and the two-limb key paths."""
+    from benchmarks.common import count_primitive
+    j = _merge_jaxpr(assume_hi_zero).jaxpr
+    assert count_primitive(j, "sort") == 2
+    assert count_primitive(j, "gather") == 0
+    assert count_primitive(j, "while") == 0
 
 
 def test_superbatch_matches_per_chunk_ingest():
