@@ -8,10 +8,19 @@ exactly (ICI within a pod) × (DCN across pods):
     mesh axes ("pod", "data"):  "data" = workers inside one data center,
                                 "pod"  = data centers.
 
-``sketch_shard`` runs per device inside ``shard_map``: quantize → pack →
-local Count Sketch + local exact top-L candidates.  ``psum`` over "data"
-then "pod" merges the sketches (linearity!), ``all_gather`` shares the
-candidate keys, and every device recovers the same global heavy hitters.
+One geo path, two programs.  The sites' folds live in one
+``stream.IngestState`` whose leaves have a leading (sites,) axis sharded
+over the mesh: :func:`fold_sites` (``stream.ingest_step`` in a
+``lax.scan`` under ``shard_map``, no collective) folds every site's own
+points in place, and :func:`merge_sites` is the master's merge —
+``psum`` of the sketch tables, counts and eviction watermarks over
+"data" then "pod" (linearity!), ``all_gather`` of the reservoirs, and
+the global top-K estimated on the merged sketch, replicated on every
+device.  ``SnsService`` (``SnsConfig.sites``) runs them on the 1-D
+``"site"`` mesh of :func:`site_mesh`; ``geo_extract_from_shards`` folds
+points made inside the program per site, and the one-shot
+``geo_extract`` (``sketch_shard``: local top-L, no reservoir) ends in
+the same merge.
 
 Privacy note (paper §V): only hashed, signed *sums* ever cross the pod
 axis — the sketch is non-invertible; raw coordinates never leave a shard.
@@ -29,8 +38,9 @@ whole ingest → HH → embed chain can run without leaving ``shard_map``.
 
 Failure semantics (two tiers, by construction):
 
-* The SPMD paths above (``geo_extract``, ``geo_extract_from_shards``)
-  run inside ONE ``shard_map`` program — XLA collectives cannot lose a
+* The SPMD paths above (``geo_extract``, ``geo_extract_from_shards``,
+  the sites of ``SnsService``) run as ``shard_map`` programs — XLA
+  collectives cannot lose a
   participant, so a dead device fails the whole dispatch.  Nothing is
   retried and nothing degrades; this tier is all-or-nothing by design.
 * :func:`resilient_extract` is the HOST-level topology the paper
@@ -44,11 +54,13 @@ Failure semantics (two tiers, by construction):
 """
 from __future__ import annotations
 
+import functools
 from typing import (Callable, Dict, List, Mapping, NamedTuple, Optional,
                     Sequence, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import candidates as cand_mod
@@ -67,11 +79,122 @@ class GeoSketchResult(NamedTuple):
     hh: HeavyHitters            # replicated global top-K
     merged: CountSketch         # replicated merged sketch
     total_count: jnp.ndarray    # psum'd global item count (stream mass)
-    # pmax'd candidate-stage watermark: the largest count any shard ever
-    # withheld from the candidate set (local top-L truncation in the
-    # one-shot path, reservoir eviction in the streaming path); 0 ⇒ every
+    # psum'd candidate-stage watermark: each site's largest count ever
+    # withheld from its candidate set (local top-L truncation in the
+    # one-shot path, reservoir eviction in the streaming path), summed
+    # over the sites — a cell no site proposed had at most its site's
+    # watermark at each site, so at most the sum in all; 0 ⇒ every
     # occupied cell was proposed — the HH candidate set is complete
     evict_max: jnp.ndarray
+
+
+SITE_AXIS = "site"     # the 1-D mesh axis of SnsService's sites
+
+
+def site_mesh(sites: int) -> Mesh:
+    """The 1-D ``"site"`` mesh over ``jax.devices()[:min(sites, n)]``:
+    one site per device where there are enough, else ``sites / n`` sites
+    on each (``sites`` must divide evenly across the devices)."""
+    devs = jax.devices()[:min(sites, len(jax.devices()))]
+    if sites % len(devs):
+        raise ValueError(f"{sites} sites do not divide evenly across "
+                         f"{len(devs)} devices")
+    return Mesh(np.asarray(devs), (SITE_AXIS,))
+
+
+def init_sites(mesh: Mesh, key: jax.Array, rows: int, log2_cols: int,
+               pool: int, sites: int) -> stream_mod.IngestState:
+    """``sites`` fresh folds as one state whose leaves have a leading
+    (sites,) axis, sharded over the mesh's axes.  Every site draws its
+    hash parameters from the same ``key`` (the paper's shared-hash
+    rule), so the sites' sketches merge by addition."""
+    st = stream_mod.init(key, rows, log2_cols, pool)
+    st = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x, (sites,) + x.shape), st)
+    return jax.device_put(st, NamedSharding(mesh, P(mesh.axis_names)))
+
+
+def _each_site(fold, *args):
+    """``fold`` over the leading (local sites,) axis of ``args`` inside a
+    ``shard_map`` body: one site on this device is folded as it is (the
+    one-site program), several one after another (``lax.map``)."""
+    if jax.tree_util.tree_leaves(args[0])[0].shape[0] == 1:
+        out = fold(*jax.tree_util.tree_map(lambda x: x[0], args))
+        return jax.tree_util.tree_map(lambda x: x[None], out)
+    return jax.lax.map(lambda a: fold(*a), args)
+
+
+@functools.partial(jax.jit, static_argnames=("grid", "mesh"),
+                   donate_argnums=(0,))
+def fold_sites(state: stream_mod.IngestState, points: jnp.ndarray,
+               mask: jnp.ndarray, *, grid: GridSpec, mesh: Mesh
+               ) -> stream_mod.IngestState:
+    """Every site folds its own (B, chunk, D) superbatch of the
+    (sites, B, chunk, D) ``points`` into its own state, in one dispatch:
+    ``stream.fold_superbatch`` (``ingest_step`` in a ``lax.scan``) under
+    ``shard_map`` over the mesh's axes, the state donated.  No
+    collective: sites never talk while they fold."""
+    spec = P(mesh.axis_names)
+
+    @shard_map_compat(mesh=mesh, in_specs=(spec, spec, spec),
+                      out_specs=spec)
+    def spmd(st, pts, m):
+        return _each_site(
+            lambda s, p, mk: stream_mod.fold_superbatch(s, grid, p, mk),
+            st, pts, m)
+
+    return spmd(state, points, mask)
+
+
+def _merge_local(st: stream_mod.IngestState, top_k: int,
+                 axes: Tuple[str, ...]) -> GeoSketchResult:
+    """The master's merge, inside a ``shard_map`` body whose ``st`` holds
+    this device's sites on a leading axis: ``psum`` of the tables, the
+    counts and the watermarks (innermost axis first), ``all_gather`` of
+    the reservoirs, then the top ``top_k`` of the gathered keys estimated
+    on the merged sketch.  Only these summaries cross devices."""
+    with jax.named_scope("sns.geo.psum"):
+        sums = (jnp.sum(st.sketch.table, axis=0), jnp.sum(st.count),
+                jnp.sum(st.evict_max))
+        for ax in axes:
+            sums = jax.lax.psum(sums, ax)
+        table, total, evict = sums
+    with jax.named_scope("sns.geo.gather"):
+        local = jax.tree_util.tree_map(
+            lambda x: x.reshape((-1,) + x.shape[2:]), st.cands)
+        gathered = cand_mod.all_gather(local, axes)
+    with jax.named_scope("sns.geo.extract"):
+        merged = CountSketch(table=table, params=jax.tree_util.tree_map(
+            lambda p: p[0], st.sketch.params))
+        hh = hh_mod.from_candidates(merged, gathered, top_k)
+    return GeoSketchResult(hh=hh, merged=merged, total_count=total,
+                           evict_max=evict)
+
+
+def merge_shard(sk_local: CountSketch, cands: Candidates,
+                count: jnp.ndarray, evict_max: jnp.ndarray, top_k: int,
+                axes: Tuple[str, ...]) -> GeoSketchResult:
+    """:func:`_merge_local` of the one site a device holds, given as its
+    sketch, candidates, count and watermark (inside ``shard_map``)."""
+    st = stream_mod.IngestState(sketch=sk_local, cands=cands, count=count,
+                                evict_max=evict_max)
+    return _merge_local(jax.tree_util.tree_map(lambda x: x[None], st),
+                        top_k, axes)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "top_k", "axes"))
+def merge_sites(state: stream_mod.IngestState, *, mesh: Mesh, top_k: int,
+                axes: Optional[Tuple[str, ...]] = None) -> GeoSketchResult:
+    """One SPMD program: the sites' folds (leading axis sharded over
+    ``axes``, all the mesh's by default) merged into the replicated
+    global view (:func:`_merge_local`)."""
+    axes = tuple(axes or mesh.axis_names)
+
+    @shard_map_compat(mesh=mesh, in_specs=(P(axes),), out_specs=P())
+    def spmd(st):
+        return _merge_local(st, top_k, axes)
+
+    return spmd(state)
 
 
 def sketch_shard(sk: CountSketch, grid: GridSpec, points: jnp.ndarray,
@@ -112,22 +235,15 @@ def geo_extract(mesh: Mesh, grid: GridSpec, points: jnp.ndarray,
     sk0 = sketch_mod.init(jax.random.key(seed), rows, log2_cols)
 
     pspec = P(tuple(data_axes))
-    in_specs = (P(), pspec)           # sketch replicated, points sharded
-    out_specs = (P(), P(), P(), P())  # everything replicated afterwards
 
-    @shard_map_compat(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    @shard_map_compat(mesh=mesh, in_specs=(P(), pspec), out_specs=P())
     def spmd(sk, pts):
         sk_local, cands, dropped = sketch_shard(sk, grid, pts, pool)
-        hh, merged = hh_mod.distributed_extract(
-            sk_local, cands, top_k, merge_axes=tuple(data_axes))
-        n_local = jnp.full((), pts.shape[0], jnp.float32)
-        total = jax.lax.psum(n_local, tuple(data_axes))
-        evict = jax.lax.pmax(dropped, tuple(data_axes))
-        return hh, merged, total, evict
+        return merge_shard(sk_local, cands,
+                           jnp.full((), pts.shape[0], jnp.float32),
+                           dropped, top_k, tuple(data_axes))
 
-    hh, merged, total, evict = spmd(sk0, points)
-    return GeoSketchResult(hh=hh, merged=merged, total_count=total,
-                           evict_max=evict)
+    return spmd(sk0, points)
 
 
 def geo_extract_from_shards(mesh: Mesh, grid: GridSpec,
@@ -152,16 +268,20 @@ def geo_extract_from_shards(mesh: Mesh, grid: GridSpec,
     behaviour via the jaxpr.)  The step is the fused single-sort fold
     (``stream.ingest_step``): one sort per batch feeds both the sketch
     scatter and the sorted-merge reservoir update —
-    tests/test_fused_ingest.py pins the one-sort-per-step jaxpr.
+    tests/test_fused_ingest.py pins the one-sort-per-step jaxpr.  Each
+    device's fold leaves the program as its site's slice of a sharded
+    state, and :func:`merge_sites` merges the sites as it does for
+    ``SnsService``.
     """
     if isinstance(data_axes, str):
         data_axes = (data_axes,)
+    data_axes = tuple(data_axes)
     pool = candidate_pool or 2 * top_k
     sk0 = sketch_mod.init(jax.random.key(seed), rows, log2_cols)
 
-    @shard_map_compat(mesh=mesh, in_specs=(P(),),
-                      out_specs=(P(), P(), P(), P()))
-    def spmd(sk):
+    @jax.jit
+    @shard_map_compat(mesh=mesh, in_specs=(P(),), out_specs=P(data_axes))
+    def fold(sk):
         idx = mesh_mod.linear_index(mesh, data_axes)
 
         def step(st, b):
@@ -171,15 +291,9 @@ def geo_extract_from_shards(mesh: Mesh, grid: GridSpec,
         st0 = stream_mod.from_sketch(sk, pool)
         st, _ = jax.lax.scan(step, st0,
                              jnp.arange(num_batches, dtype=jnp.int32))
-        hh, merged = hh_mod.distributed_extract(
-            st.sketch, st.cands, top_k, merge_axes=tuple(data_axes))
-        total = jax.lax.psum(st.count, tuple(data_axes))
-        evict = jax.lax.pmax(st.evict_max, tuple(data_axes))
-        return hh, merged, total, evict
+        return jax.tree_util.tree_map(lambda x: x[None], st)
 
-    hh, merged, total, evict = spmd(sk0)
-    return GeoSketchResult(hh=hh, merged=merged, total_count=total,
-                           evict_max=evict)
+    return merge_sites(fold(sk0), mesh=mesh, top_k=top_k, axes=data_axes)
 
 
 class ResilientExtractResult(NamedTuple):

@@ -1,7 +1,8 @@
 """Global heavy-hitter recovery: candidates × merged sketch → top-K cells.
 
-Single-shard ("exact") and distributed (SPMD) variants.  The distributed
-variant is the paper's geo-distributed topology mapped onto a device mesh:
+Single-shard here; the distributed (SPMD) merge is ``geo._merge_local``,
+which ends in :func:`from_candidates`.  It maps the paper's
+geo-distributed topology onto a device mesh:
 
     per-device:  quantize → pack → local sketch update + local top-L
     data axis :  psum(sketch)           [paper: merge within a data center]
@@ -15,7 +16,7 @@ aggregation-site straggler.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,27 +55,6 @@ def extract(sk: CountSketch, key_hi: jnp.ndarray, key_lo: jnp.ndarray,
     cands = cand_mod.local_topk(key_hi, key_lo, pool,
                                 values=values, mask=mask)
     return from_candidates(sk, cands, k)
-
-
-def distributed_extract(
-        sk_local: CountSketch, cands_local: Candidates, k: int,
-        merge_axes: Union[str, Sequence[str]],
-) -> Tuple[HeavyHitters, CountSketch]:
-    """SPMD global HH extraction (call inside shard_map / jit-with-mesh).
-
-    ``merge_axes``: mesh axis name(s) the data is sharded over, innermost
-    (fast interconnect) first, e.g. ``("data",)`` or ``("data", "pod")``.
-    Returns (replicated HH list, merged sketch).
-    """
-    if isinstance(merge_axes, str):
-        merge_axes = (merge_axes,)
-    merged = sk_local
-    for ax in merge_axes:           # hierarchical: ICI first, DCN second
-        merged = sketch_mod.psum_merge(merged, ax)
-    gathered = cands_local
-    for ax in merge_axes:
-        gathered = cand_mod.all_gather(gathered, ax)
-    return from_candidates(merged, gathered, k), merged
 
 
 def exact_counts(key_hi: jnp.ndarray, key_lo: jnp.ndarray,

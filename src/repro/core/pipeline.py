@@ -92,6 +92,10 @@ class SnsConfig:
     # force one tier for every registry op (cic splat/gather, tSNE force
     # tile, kNN distance scan, the fused segment-reduce).
     kernel_mode: str = "auto"
+    # sites of the deployment (paper §V): SnsService keeps one fold per
+    # site, sharded over jax.devices()[:min(sites, n)] (sites dividing
+    # evenly across them), and the master merges them; 1 = one fold
+    sites: int = 1
     seed: int = 0
 
     def __post_init__(self):
@@ -150,6 +154,7 @@ class SnsConfig:
             (self.kernel_mode in ("auto", "compiled", "interpret", "xla"),
              f"kernel_mode must be 'auto'|'compiled'|'interpret'|'xla', "
              f"got {self.kernel_mode!r}"),
+            (self.sites >= 1, f"sites must be >= 1, got {self.sites}"),
         ]
         bad = [msg for ok, msg in checks if not ok]
         if bad:
@@ -426,7 +431,7 @@ def run_streaming(cfg: SnsConfig, chunks=None,
             top_k=cfg.top_k, candidate_pool=cfg.candidate_pool,
             data_axes=data_axes, seed=cfg.seed, num_batches=num_batches)
         hh, total = res.hh, float(res.total_count)
-        bound = float(res.evict_max)   # pmax'd per-shard watermark
+        bound = float(res.evict_max)   # summed per-site watermark
     else:
         if chunks is None:
             raise ValueError("single-host streaming needs a chunk source")

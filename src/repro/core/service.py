@@ -30,6 +30,16 @@ operations, each a distinct perf lever:
   is O(chunk · N_reps), never (Q, N_reps), and high query traffic serves
   at batched-millisecond latency.
 
+* Several sites (``SnsConfig.sites``, paper §V): one fold per site,
+  held on a leading (sites,) axis of the state and sharded one site per
+  device where there are enough (``geo.site_mesh``).  ``update()``
+  takes one chunk source per site and folds each on its own device in
+  one dispatch, with no collective; :meth:`SnsService.merge` is the
+  master's merge (``geo.merge_sites``), whose view refresh and so
+  transform read; the error bound sums the sites' watermarks as the
+  merge does.  ``save``/``load``/``update_shards``
+  keep one fold and refuse a service of several sites.
+
 The grid is fixed at construction (the paper's shared-hypercube
 contract): cell keys — the identity that the warm-start matching relies
 on — are only comparable across refreshes under one grid.
@@ -66,6 +76,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core import geo
@@ -188,8 +199,17 @@ class SnsService:
         self._ecfg = pipeline.resolve_embed_cfg(cfg, tsne_cfg=tsne_cfg,
                                                 umap_cfg=umap_cfg)
         pool = cfg.candidate_pool or 2 * cfg.top_k
-        self.state = stream_mod.init(jax.random.key(cfg.seed), cfg.rows,
-                                     cfg.log2_cols, pool)
+        self.sites = cfg.sites
+        self.mesh = None
+        if self.sites == 1:
+            self.state = stream_mod.init(jax.random.key(cfg.seed), cfg.rows,
+                                         cfg.log2_cols, pool)
+        else:
+            self.mesh = geo.site_mesh(self.sites)
+            self.state = geo.init_sites(self.mesh, jax.random.key(cfg.seed),
+                                        cfg.rows, cfg.log2_cols, pool,
+                                        self.sites)
+        self._merges = 0
         self._cache: Optional[EmbedCache] = None
         self._pending = 0.0   # mass ingested since the last refresh
         # resilience / health bookkeeping (see health())
@@ -206,30 +226,114 @@ class SnsService:
         self._last_refresh: Optional[Dict[str, object]] = None
 
     # ------------------------------------------------------------ ingest
+    @property
+    def state(self) -> stream_mod.IngestState:
+        """The live fold; with several sites, every site's fold on a
+        leading (sites,) axis sharded over :attr:`mesh`.  Assigning it
+        drops the merged view (:meth:`merge`)."""
+        return self._state
+
+    @state.setter
+    def state(self, state: stream_mod.IngestState) -> None:
+        self._state = state
+        self._view = None
+
+    def _site_points(self) -> np.ndarray:
+        """Points folded so far, per site (blocks on in-flight folds)."""
+        return np.atleast_1d(np.asarray(self.state.count, np.float64))
+
+    def _points(self) -> float:
+        if self.sites == 1:
+            return float(self.state.count)
+        return float(self._site_points().sum())
+
     def update(self, chunks) -> Dict[str, float]:
         """Fold new data into the live ingest state (no history re-read).
 
         ``chunks``: a single (n, D) array, an iterable of them, or a
-        zero-arg callable factory.  Returns absorption stats — points
-        folded, wall seconds (device-synced), points/sec — plus the
-        current drift picture (``pending_fraction``, ``needs_refresh``).
+        zero-arg callable factory; with several sites
+        (``SnsConfig.sites``), a sequence of one such source per site,
+        each folded into its own site's state on its own device
+        (``stream.ingest_sites`` feeding ``geo.fold_sites``).  Returns
+        absorption stats — points folded, wall seconds (device-synced),
+        points/sec — plus the current drift picture
+        (``pending_fraction``, ``needs_refresh``).
         """
-        if pipeline._is_points_array(chunks):
-            chunks = [chunks]
         with obs.span("sns.update.wait"):
-            before = float(self.state.count)     # syncs any in-flight fold
+            before = self._points()              # syncs any in-flight fold
         t0 = time.perf_counter()
-        self.state = stream_mod.ingest_all(
-            self.state, self.grid, pipeline._chunk_stream(chunks),
-            self.cfg.ingest_chunk, superbatch=self.cfg.ingest_superbatch)
+        if self.sites == 1:
+            if pipeline._is_points_array(chunks):
+                chunks = [chunks]
+            self.state = stream_mod.ingest_all(
+                self.state, self.grid, pipeline._chunk_stream(chunks),
+                self.cfg.ingest_chunk,
+                superbatch=self.cfg.ingest_superbatch)
+        else:
+            self.state = self._ingest_sites(chunks)
         with obs.span("sns.update.wait"):
-            absorbed = float(self.state.count) - before   # blocks on the fold
+            absorbed = self._points() - before   # blocks on the fold
         dt = time.perf_counter() - t0
         self._pending += absorbed
         return {"points": absorbed, "seconds": dt,
                 "points_per_sec": absorbed / dt if dt > 0 else 0.0,
                 "pending_fraction": self.pending_fraction(),
                 "needs_refresh": self.needs_refresh()}
+
+    def _ingest_sites(self, site_chunks) -> stream_mod.IngestState:
+        site_chunks = list(site_chunks)
+        if len(site_chunks) != self.sites:
+            raise ValueError(f"update() of a {self.sites}-site service "
+                             f"takes one chunk source per site, got "
+                             f"{len(site_chunks)}")
+        streams = [pipeline._chunk_stream([c] if pipeline._is_points_array(c)
+                                          else c) for c in site_chunks]
+        fold = functools.partial(geo.fold_sites, grid=self.grid,
+                                 mesh=self.mesh)
+        return stream_mod.ingest_sites(
+            self.state, streams, self.cfg.ingest_chunk,
+            self.cfg.ingest_superbatch, fold,
+            NamedSharding(self.mesh, P(geo.SITE_AXIS)))
+
+    def merge(self) -> geo.GeoSketchResult:
+        """The master's merged view of every site's fold: the global
+        top-K heavy hitters, the merged sketch, the count and the summed
+        eviction watermark (``geo.merge_sites``: one SPMD program of
+        ``psum``, ``all_gather`` and the top-K).  Kept until the next
+        :meth:`update`; :meth:`refresh` and so :meth:`transform` read
+        it.  With one site it is that site's own
+        fold, and nothing is merged.
+
+        Host span ``sns.geo.merge`` (the dispatch and the blocking read);
+        counters ``sns.geo.merges`` and ``sns.geo.merge_bytes`` (the
+        table and reservoir bytes every site ships, summed)."""
+        if self._view is not None:
+            return self._view
+        st = self.state
+        if self.sites == 1:
+            self._view = geo.GeoSketchResult(
+                hh=hh_mod.from_candidates(st.sketch, st.cands,
+                                          self.cfg.top_k),
+                merged=st.sketch, total_count=st.count,
+                evict_max=st.evict_max)
+            return self._view
+        with obs.span("sns.geo.merge"):
+            view = jax.block_until_ready(geo.merge_sites(
+                st, mesh=self.mesh, top_k=self.cfg.top_k))
+        obs.count("sns.geo.merges")
+        obs.count("sns.geo.merge_bytes", sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(
+                (st.sketch.table, st.cands))))
+        self._merges += 1
+        self._view = view
+        return view
+
+    def _check_one_site(self, what: str) -> None:
+        if self.sites != 1:
+            raise NotImplementedError(
+                f"{what} keeps one fold; this service has {self.sites} "
+                "sites (update() takes one source per site, merge() "
+                "serves their merged view)")
 
     def update_shards(self, shard_chunks, *,
                       policy: Optional[resilience.RetryPolicy] = None,
@@ -250,6 +354,7 @@ class SnsService:
         :class:`~repro.core.resilience.CoverageError` WITHOUT touching
         the live fold.
         """
+        self._check_one_site("update_shards()")
         if not isinstance(shard_chunks, dict):
             shard_chunks = dict(enumerate(shard_chunks))
         pool = int(self.state.cands.capacity)
@@ -298,7 +403,7 @@ class SnsService:
     def pending_fraction(self) -> float:
         """Fraction of all ingested mass not yet reflected in the served
         embedding (1.0 before the first refresh)."""
-        total = float(self.state.count)
+        total = self._points()
         return self._pending / total if total > 0 else 0.0
 
     def needs_refresh(self) -> bool:
@@ -313,15 +418,20 @@ class SnsService:
     def error_bound(self) -> float:
         """Served per-cell count error bound: the space-saving eviction
         watermark widened by the mass of any shards lost in
-        :meth:`update_shards` (resilience.widened_bound)."""
-        return resilience.widened_bound(
-            float(stream_mod.space_saving_bound(self.state)),
-            self._lost_mass)
+        :meth:`update_shards` (resilience.widened_bound); with several
+        sites, the watermarks summed over the sites, as :meth:`merge`
+        sums them (without running a merge)."""
+        if self.sites == 1:
+            watermark = float(stream_mod.space_saving_bound(self.state))
+        else:
+            watermark = float(np.asarray(self.state.evict_max,
+                                         np.float64).sum())
+        return resilience.widened_bound(watermark, self._lost_mass)
 
     def coverage(self) -> float:
         """Fraction of the offered stream actually folded (1.0 when no
         shard has ever been lost)."""
-        seen = float(self.state.count)
+        seen = self._points()
         offered = seen + self._lost_mass
         return seen / offered if offered > 0 else 1.0
 
@@ -366,8 +476,7 @@ class SnsService:
         """Build the next serving snapshot entirely off to the side.
         Returns (EmbedCache, RefreshResult); never mutates self."""
         cfg = self.cfg
-        hh = hh_mod.from_candidates(self.state.sketch, self.state.cands,
-                                    cfg.top_k)
+        hh = self.merge().hh
         # same key discipline as pipeline.embed_stage: reps and optimizer
         # draws are bit-reproducible for a given (seed, HH set)
         krep, kembed = jax.random.split(jax.random.key(cfg.seed + 1))
@@ -462,12 +571,14 @@ class SnsService:
         records the most recent refresh outcome (including failures the
         transactional swap rolled back); ``ingest`` holds the
         process-wide host spans and counters of the ingest path
-        (``repro.obs.snapshot()``: ``sns.ingest.*``, ``sns.update.wait``)."""
+        (``repro.obs.snapshot()``: ``sns.ingest.*``, ``sns.update.wait``,
+        ``sns.geo.*``); ``geo`` the sites, the devices they are spread
+        over, each site's points and the merges run."""
         c = self._cache
         return {
             "serving": c is not None,
             "n_reps": int(c.rep_y.shape[0]) if c is not None else 0,
-            "points": float(self.state.count),
+            "points": self._points(),
             "pending_fraction": self.pending_fraction(),
             "needs_refresh": self.needs_refresh(),
             "hh_error_bound": self.error_bound(),
@@ -487,6 +598,11 @@ class SnsService:
             "refresh_failures": self._refresh_failures,
             "last_refresh": self._last_refresh,
             "ingest": obs.snapshot(),
+            "geo": {"sites": self.sites,
+                    "devices": 1 if self.mesh is None
+                    else int(self.mesh.devices.size),
+                    "site_points": self._site_points().tolist(),
+                    "merges": self._merges},
         }
 
     # --------------------------------------------------------- transform
@@ -524,6 +640,7 @@ class SnsService:
         and checksummed, and the previous checkpoint generation rotates
         to ``<path>.npz.bak`` — :meth:`load` falls back to it if this
         write is later found torn or corrupted."""
+        self._check_one_site("save()")
         if self._cache is None:
             raise ServiceNotReadyError(
                 "save() checkpoints the serving snapshot; call refresh() "
@@ -553,6 +670,7 @@ class SnsService:
         (``stream.load_state(fallback=True)``)."""
         svc = cls(cfg, grid, tsne_cfg=tsne_cfg, umap_cfg=umap_cfg,
                   service_cfg=service_cfg)
+        svc._check_one_site("load()")
         state, extras = stream_mod.load_state(path, with_extra=True,
                                               fallback=True)
         svc.state = state

@@ -55,8 +55,9 @@ reservoirs sorted-merge, counts add, eviction watermarks max) — the
 partial-aggregation backbone of ``core.resilience``.
 
 Used by the single-host streaming pipeline (``pipeline.run_streaming``)
-and, via ``ingest_step`` inside ``lax.scan``, by the mesh streaming path
-(``geo.geo_extract_from_shards``).
+and, via ``ingest_step`` inside ``lax.scan``, by the mesh paths: the
+sites of ``SnsService`` (``geo.fold_sites``, fed by :func:`ingest_sites`)
+and ``geo.geo_extract_from_shards``.
 """
 from __future__ import annotations
 
@@ -165,6 +166,14 @@ def ingest_superbatch(state: IngestState, points: jnp.ndarray,
     O(1) in B while the Python-loop/dispatch overhead is paid once per
     superbatch instead of once per chunk.  Fully-masked chunks are
     no-ops — the host driver pads the ragged tail superbatch with them."""
+    return fold_superbatch(state, grid, points, mask)
+
+
+def fold_superbatch(state: IngestState, grid: GridSpec, points: jnp.ndarray,
+                    mask: jnp.ndarray) -> IngestState:
+    """Traceable body of :func:`ingest_superbatch`: ``ingest_step`` in a
+    ``lax.scan`` over the leading axis of (B, chunk, D) points and (B,
+    chunk) masks.  Also one site's fold under ``geo.fold_sites``."""
     def step(st, batch):
         pts, m = batch
         return ingest_step(st, grid, pts, mask=m), ()
@@ -254,7 +263,19 @@ def ingest_all(state: IngestState, grid: GridSpec,
     else:
         batches = _superbatches(rechunk(chunks, chunk_size), superbatch)
         fold = ingest_superbatch
+    return _drive(state, batches,
+                  lambda st, pts, mask: fold(st, pts, mask, grid=grid),
+                  lambda pts, mask: (jax.device_put(pts),
+                                     jax.device_put(mask)))
 
+
+def _drive(state: IngestState, batches, fold, put) -> IngestState:
+    """The double-buffered host loop of :func:`ingest_all` and
+    :func:`ingest_sites`: take the next (points, mask) block of
+    ``batches``, ``put`` it on the device(s), ``fold`` it into the state;
+    the fold is dispatched asynchronously, so assembling and putting the
+    next block overlaps the running one.  Spans and counters as
+    documented in :func:`ingest_all`."""
     def _next():
         with obs.span("sns.ingest.assemble"):
             batch = next(batches, None)
@@ -262,7 +283,7 @@ def ingest_all(state: IngestState, grid: GridSpec,
             return None
         pts, mask = batch
         with obs.span("sns.ingest.put"):
-            out = jax.device_put(pts), jax.device_put(mask)
+            out = put(pts, mask)
         obs.count("sns.ingest.put_bytes", pts.nbytes + mask.nbytes)
         return out + (int(np.count_nonzero(mask)), mask.size)
 
@@ -270,7 +291,7 @@ def ingest_all(state: IngestState, grid: GridSpec,
     while nxt is not None:
         pts, mask, points, rows = nxt
         with obs.span("sns.ingest.dispatch"):
-            state = fold(state, pts, mask, grid=grid)
+            state = fold(state, pts, mask)
         obs.count("sns.ingest.points", points)
         obs.count("sns.ingest.rows", rows)
         obs.count("sns.ingest.superbatches")
@@ -278,6 +299,44 @@ def ingest_all(state: IngestState, grid: GridSpec,
         # the next batch here overlaps the device-side compute
         nxt = _next()
     return state
+
+
+def ingest_sites(state: IngestState, site_chunks, chunk_size: int,
+                 superbatch: int, fold, sharding) -> IngestState:
+    """Drive a sharded fold over one chunk stream per site.
+
+    ``state`` holds every site's fold with a leading (sites,) axis;
+    ``site_chunks`` gives one host-side chunk source per site, each
+    rechunked and stacked as :func:`ingest_all` does.  Each dispatch
+    stacks the sites' next superbatches into one (sites, B, chunk, D)
+    block and its (sites, B, chunk) mask, and puts both with one
+    ``device_put`` against ``sharding``, so each site's rows go straight
+    to the device that holds its fold; ``fold(state, points, mask)``
+    folds every site in one dispatch.  A site whose stream ends first is
+    fed fully-masked (no-op) blocks.  The next block is assembled and
+    put while the current one folds (the same double buffering).
+
+    Host spans and counters as in :func:`ingest_all`, counting the rows
+    of every site."""
+    feeds = [_superbatches(rechunk(c, chunk_size), superbatch)
+             for c in site_chunks]
+    return _drive(state, _stacked(feeds), fold,
+                  lambda pts, mask: jax.device_put((pts, mask), sharding))
+
+
+def _stacked(feeds) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
+    """(sites, B, chunk, D) + (sites, B, chunk) blocks of every site's
+    next superbatch, until every feed ends; a feed that ended gives
+    fully-masked blocks meanwhile."""
+    while True:
+        blocks = [next(f, None) for f in feeds]
+        live = [b for b in blocks if b is not None]
+        if not live:
+            return
+        pad = (np.zeros_like(live[0][0]), np.zeros_like(live[0][1]))
+        blocks = [pad if b is None else b for b in blocks]
+        yield (np.stack([b[0] for b in blocks]),
+               np.stack([b[1] for b in blocks]))
 
 
 def merge_states(a: IngestState, b: IngestState) -> IngestState:

@@ -1,8 +1,10 @@
 """Dry-run + roofline for the paper's OWN pipeline at pod scale.
 
-Lowers ``geo_extract``'s SPMD program (quantize → pack → per-device
-Count Sketch update + local top-L → hierarchical psum merge → all-gather
-candidates → global top-K) on the production mesh, with a configurable
+Lowers a ``geo_extract``-shaped SPMD program (quantize → pack →
+per-device Count Sketch update + local top-L → ``geo.merge_shard``, the
+master's merge that ``SnsService`` runs: hierarchical psum of the tables,
+counts and watermarks → all-gather candidates → global top-K) on the
+production mesh, with a configurable
 per-device batch: 512 devices × 2²⁰ points/step ≈ 5.4·10⁸ points per
 step — the paper's "billions across data centers" regime is a few such
 steps.
@@ -26,7 +28,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import candidates as cand_mod
 from repro.core import geo
-from repro.core import heavy_hitters as hh_mod
 from repro.core import quantize, sketch as sketch_mod
 from repro.core.quantize import GridSpec
 from repro.launch.hlo_analysis import analyze_hlo
@@ -62,14 +63,15 @@ def main() -> None:
         else sketch_mod.update
 
     @geo.shard_map_compat(mesh=mesh, in_specs=(P(), P(data_axes)),
-                          out_specs=(P(), P()))
+                          out_specs=P())
     def spmd(sk, pts):
         key_hi, key_lo = quantize.points_to_keys(grid, pts)
         sk_local = upd(sk, key_hi, key_lo)
-        cands = cand_mod.local_topk(key_hi, key_lo, pool)
-        hh, merged = hh_mod.distributed_extract(
-            sk_local, cands, args.top_k, merge_axes=data_axes)
-        return hh, merged
+        cands, dropped = cand_mod.topk_from_runs(
+            cand_mod.sorted_runs(key_hi, key_lo), pool, return_dropped=True)
+        return geo.merge_shard(sk_local, cands,
+                               jnp.full((), pts.shape[0], jnp.float32),
+                               dropped, args.top_k, data_axes)
 
     pts_spec = jax.ShapeDtypeStruct((n_total, args.dims), jnp.float32)
     sk_spec = jax.eval_shape(lambda: sk0)
